@@ -5,12 +5,15 @@ baseline step (exactly as bench.py builds them) and reports whether they
 differ.  Identical HLO => any persistent timing delta is measurement
 noise and vs_baseline should read ~1.0.
 
-Run: python benchmarks/hlo_diff.py  (CPU or TPU; module structure only)
+Run from repo root: python benchmarks/hlo_diff.py  (CPU or TPU; module
+structure only)
 """
 
 import difflib
 import re
 import sys
+
+sys.path.insert(0, ".")
 
 import numpy as np
 
@@ -40,22 +43,18 @@ def canon(text: str) -> str:
 def main():
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
+    import bench
     import zhpe_ompi_tpu as zmpi
-    from zhpe_ompi_tpu import compat
     from zhpe_ompi_tpu.models import transformer as tfm
 
-    devs = jax.devices()
-    n = len(devs)
-    tp = 2 if n % 2 == 0 else 1
-    dp = n // tp
-    mesh = Mesh(np.asarray(devs[: dp * tp]).reshape(dp, tp), ("dp", "tp"))
+    mesh = bench.dp_tp_mesh(jax.devices())
+    dp, tp = mesh.shape["dp"], mesh.shape["tp"]
     dp_comm = zmpi.Communicator(mesh, "dp", name="hlo_dp")
     tp_comm = zmpi.Communicator(mesh, "tp", name="hlo_tp") if tp > 1 else None
 
-    on_tpu = devs[0].platform not in ("cpu",)
-    if on_tpu:
+    if jax.devices()[0].platform == "tpu":
         cfg = tfm.Config(vocab=8192, d_model=1024, n_heads=16, d_ff=4096,
                          n_layers=4, seq=512, dtype=jnp.bfloat16)
         batch = 8 * dp
@@ -70,44 +69,7 @@ def main():
     targets = jnp.asarray(r.integers(0, cfg.vocab, (batch, cfg.seq)))
 
     step_fw, specs = tfm.make_train_step(cfg, mesh, dp_comm, tp_comm)
-
-    # rebuild the plain step exactly as bench.py does
-    from jax import lax
-
-    class RawComm:
-        def __init__(self, axis):
-            self.axis = axis
-
-        def allreduce(self, x, op):
-            return lax.psum(x, self.axis)
-
-    raw_tp = RawComm("tp") if tp > 1 else None
-
-    def spmd_step(p, tok, tgt):
-        def local_loss(pp):
-            return tfm.loss_fn(pp, tok, tgt, cfg, raw_tp)
-
-        loss, grads = jax.value_and_grad(local_loss)(p)
-        synced = {}
-        replicated = {"embed", "lnf", "ln1", "ln2"}
-        for name, g in grads.items():
-            g = lax.psum(g, "dp") / dp
-            if name in replicated and raw_tp is not None:
-                g = lax.psum(g, "tp") / tp
-            synced[name] = g
-        loss = lax.psum(loss, "dp") / dp
-        if raw_tp is not None:
-            loss = lax.psum(loss, "tp") / tp
-        new_p = jax.tree.map(
-            lambda a, g: (a - 1e-2 * g).astype(a.dtype), p, synced
-        )
-        return new_p, loss
-
-    step_pl = jax.jit(compat.shard_map(
-        spmd_step, mesh=mesh,
-        in_specs=(specs, P("dp"), P("dp")),
-        out_specs=(specs, P()), check_vma=False,
-    ))
+    step_pl = bench.make_plain_step(cfg, mesh, specs)
 
     sharded = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
                for k, v in params.items()}
@@ -121,7 +83,6 @@ def main():
     hlo_pl = canon(
         step_pl.lower(sharded, tok, tgt).compile()
         .as_text())
-
     if hlo_fw == hlo_pl:
         print("HLO IDENTICAL: framework and plain paths compile to the "
               "same program; vs_baseline deltas are measurement noise.")

@@ -20,22 +20,18 @@ import numpy as np
 def main():
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
+    import bench
     import zhpe_ompi_tpu as zmpi
-    from zhpe_ompi_tpu import compat
     from zhpe_ompi_tpu.models import transformer as tfm
 
-    devs = jax.devices()
-    n = len(devs)
-    tp = 2 if n % 2 == 0 else 1
-    dp = n // tp
-    mesh = Mesh(np.asarray(devs[: dp * tp]).reshape(dp, tp), ("dp", "tp"))
+    mesh = bench.dp_tp_mesh(jax.devices())
+    dp, tp = mesh.shape["dp"], mesh.shape["tp"]
     dp_comm = zmpi.Communicator(mesh, "dp", name="probe_dp")
     tp_comm = zmpi.Communicator(mesh, "tp", name="probe_tp") if tp > 1 else None
 
-    on_tpu = devs[0].platform not in ("cpu",)
-    if on_tpu:
+    if jax.devices()[0].platform == "tpu":
         cfg = tfm.Config(vocab=8192, d_model=1024, n_heads=16, d_ff=4096,
                          n_layers=4, seq=512, dtype=jnp.bfloat16)
         batch, iters = 8 * dp, 20
@@ -51,42 +47,7 @@ def main():
 
     step_fw, specs = tfm.make_train_step(cfg, mesh, dp_comm, tp_comm)
     step_fw2, _ = tfm.make_train_step(cfg, mesh, dp_comm, tp_comm)
-
-    from jax import lax
-
-    class RawComm:
-        def __init__(self, axis):
-            self.axis = axis
-
-        def allreduce(self, x, op):
-            return lax.psum(x, self.axis)
-
-    raw_tp = RawComm("tp") if tp > 1 else None
-
-    def spmd_step(p, tok, tgt):
-        def local_loss(pp):
-            return tfm.loss_fn(pp, tok, tgt, cfg, raw_tp)
-
-        loss, grads = jax.value_and_grad(local_loss)(p)
-        synced = {}
-        replicated = {"embed", "lnf", "ln1", "ln2"}
-        for name, g in grads.items():
-            g = lax.psum(g, "dp") / dp
-            if name in replicated and raw_tp is not None:
-                g = lax.psum(g, "tp") / tp
-            synced[name] = g
-        loss = lax.psum(loss, "dp") / dp
-        if raw_tp is not None:
-            loss = lax.psum(loss, "tp") / tp
-        new_p = jax.tree.map(
-            lambda a, g: (a - 1e-2 * g).astype(a.dtype), p, synced
-        )
-        return new_p, loss
-
-    step_pl = jax.jit(compat.shard_map(
-        spmd_step, mesh=mesh, in_specs=(specs, P("dp"), P("dp")),
-        out_specs=(specs, P()), check_vma=False,
-    ))
+    step_pl = bench.make_plain_step(cfg, mesh, specs)
 
     def prep(step):
         sharded = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))

@@ -22,9 +22,6 @@ import numpy as np  # noqa: E402
 
 def main():
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax.numpy as jnp
 
     import zhpe_ompi_tpu as zmpi

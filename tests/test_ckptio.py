@@ -465,6 +465,7 @@ class TestRollbackLegInstrumentation:
         ck = CollectiveCheckpointer(str(tmp_path))
         ck.save(4, _state(), blocking=True)
         flightrec.arm()
+        flightrec.clear()  # a process-global ring: drop other tests' events
         try:
             flightrec.record(flightrec.DAEMON_FAULT, job="j0",
                              cause="killed", deaths=[1])

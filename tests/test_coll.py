@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 
 import zhpe_ompi_tpu as zmpi
-from zhpe_ompi_tpu import compat
 from zhpe_ompi_tpu.coll import algorithms as alg
 from zhpe_ompi_tpu.coll import tpu as xla_mod
 
@@ -428,7 +427,7 @@ class TestBarrierNotFolded:
             tok = algo(world, token=s)
             return s + tok.astype(s.dtype)
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             step, mesh=world.mesh, in_specs=P("world"), out_specs=P("world")
         )
         txt = jax.jit(fn).lower(

@@ -236,6 +236,37 @@ class TestProbeChild:
         assert deadline_mod.orphaned_probe_processes() == []
 
 
+class TestProbeInProcess:
+    """On a TPU the chip belongs to the probing process: the probe runs
+    in-process under its deadline and never starts a child."""
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        monkeypatch.setattr(mesh_mod, "_holds_tpu", lambda: True)
+
+        def no_child(*a, **kw):
+            raise AssertionError("a probe child would need the chip")
+
+        monkeypatch.setattr(deadline_mod, "run_probe", no_child)
+
+    def test_healthy_plane_answers_ok(self, on_tpu):
+        import json
+
+        kind, detail = mesh_mod.probe_device_plane(deadline=60.0)
+        assert kind == "ok", detail
+        info = json.loads(detail)
+        assert info["n"] >= 1 and info["psum"] == sum(range(info["n"]))
+
+    def test_wedge_hits_the_deadline(self, on_tpu, monkeypatch):
+        monkeypatch.setenv(coll_tpu.WEDGE_ENV, "2")
+        before = spc.read("device_probe_misses")
+        kind, _ = mesh_mod.probe_device_plane(deadline=60.0, rank=0)
+        assert kind == "ok"  # the hook names rank 2, not rank 0
+        kind, detail = mesh_mod.probe_device_plane(deadline=0.5, rank=2)
+        assert kind == "deadline", detail
+        assert spc.read("device_probe_misses") - before == 1
+
+
 class TestWedgePlan:
     def test_wedge_composes_with_kill_plans(self):
         plan = FaultPlan(seed=5).kill_ranks([1, 2], after_ops=3) \

@@ -82,6 +82,53 @@ class TestFusedLayerNorm:
         assert abs(float(l_off) - float(l_on)) < 1e-4
 
 
+class TestLayerNormDispatch:
+    """The fused-norm dispatcher: kernel on TPU, a lowering failure
+    raises, reference off-TPU and for shapes the kernel cannot tile."""
+
+    def _spy(self, monkeypatch):
+        calls = []
+        real = fnm._ln_pallas
+
+        def spy(x2, g, block, interpret):
+            calls.append(interpret)
+            return real(x2, g, block, True)  # runs on CPU
+
+        monkeypatch.setattr(fnm, "_ln_pallas", spy)
+        return calls
+
+    @pytest.mark.parametrize("tpu,want", [(True, [False]), (False, [])])
+    def test_platform_dispatch(self, monkeypatch, tpu, want):
+        monkeypatch.setattr(fnm, "on_tpu", lambda: tpu)
+        calls = self._spy(monkeypatch)
+        x = jnp.ones((4, 64, 128))
+        g = jnp.ones((128,))
+        out = fnm.layer_norm(x, g)
+        assert calls == want
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(fnm.ln_reference(x, g)),
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("shape", [(3, 5, 96), (3, 100, 128)],
+                             ids=["d%128", "rows%block"])
+    def test_untileable_shape_uses_reference_on_tpu(self, monkeypatch,
+                                                    shape):
+        monkeypatch.setattr(fnm, "on_tpu", lambda: True)
+        calls = self._spy(monkeypatch)
+        fnm.layer_norm(jnp.ones(shape), jnp.ones(shape[-1:]))
+        assert calls == []
+
+    def test_lowering_error_raises_on_tpu(self, monkeypatch):
+        monkeypatch.setattr(fnm, "on_tpu", lambda: True)
+
+        def boom(*a, **kw):
+            raise RuntimeError("Mosaic lowering unsupported")
+
+        monkeypatch.setattr(fnm, "_ln_pallas", boom)
+        with pytest.raises(RuntimeError, match="Mosaic lowering"):
+            fnm.layer_norm(jnp.ones((4, 64, 128)), jnp.ones((128,)))
+
+
 class TestChunkedCE:
     @pytest.mark.parametrize("dtype,tol", [
         (jnp.float32, 1e-5), (jnp.bfloat16, 5e-2),

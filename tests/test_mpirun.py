@@ -529,3 +529,29 @@ class TestDvm:
                 daemon.kill()
                 daemon.wait()
         assert dvm_mod.orphaned_daemon_processes() == []
+
+
+class TestTpuHost:
+    """One process per chip: on a TPU host the first rank that touches
+    JAX takes every chip, so several local ranks are refused loudly."""
+
+    @pytest.fixture
+    def tpu_host(self, monkeypatch):
+        monkeypatch.setattr(mpirun, "tpu_chips", lambda: 4)
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+
+    def test_several_ranks_are_refused(self, tpu_host, tmp_path):
+        prog = _script(tmp_path, "raise SystemExit('must not start')\n")
+        with pytest.raises(RuntimeError, match="contend for its 4 chip"):
+            _launch(2, [prog])
+
+    @pytest.mark.parametrize("n,env", [
+        (1, {}),                          # one process drives every chip
+        (4, {"JAX_PLATFORMS": "cpu"}),    # host-plane ranks pinned to CPU
+    ])
+    def test_allowed_layouts(self, tpu_host, n, env):
+        mpirun.refuse_shared_chips(n, env)
+
+    def test_host_without_chips_is_unaffected(self, monkeypatch):
+        monkeypatch.setattr(mpirun, "tpu_chips", lambda: 0)
+        mpirun.refuse_shared_chips(8, {})

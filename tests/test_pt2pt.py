@@ -5,6 +5,8 @@ datatype-engine style), then runtime smoke tests shaped like test/simple's
 ring/hello programs (SURVEY.md §4).
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -211,6 +213,14 @@ class TestUniverse:
 
         with pytest.raises(errors.InternalError):
             uni.run(main, timeout=0.5)
+        # release both parked ranks: a posted receive left behind keeps
+        # the universe live and fails a later file's quiescence check
+        uni.contexts[0].send(0, dest=1, tag=0)
+        uni.contexts[1].send(0, dest=0, tag=0)
+        deadline = time.monotonic() + 10.0
+        while any(c.engine.stats()["posted"] for c in uni.contexts):
+            assert time.monotonic() < deadline, "parked ranks never woke"
+            time.sleep(0.01)
 
     def test_rendezvous_buffer_reuse(self, fresh_vars):
         """Regression: after a rendezvous send completes, mutating the send

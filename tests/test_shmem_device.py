@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 
 import zhpe_ompi_tpu as zmpi
-from zhpe_ompi_tpu import compat
 from zhpe_ompi_tpu.core import errors
 from zhpe_ompi_tpu.shmem import spml
 from zhpe_ompi_tpu.shmem.device import DeviceHeap
@@ -366,7 +365,7 @@ class TestBarrierCost:
                     pe = pe.barrier()
                 return pe.arenas[sym.arena][None]
 
-            return lambda a: compat.shard_map(
+            return lambda a: jax.shard_map(
                 body, mesh=world.mesh, in_specs=P(world.axis),
                 out_specs=P(world.axis), check_vma=False)(a)
 

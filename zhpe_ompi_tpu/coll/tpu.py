@@ -55,8 +55,6 @@ PROBE_SRC = (
     "import json\n"
     "import jax\n"
     "import jax.numpy as jnp\n"
-    "p=os.environ.get('JAX_PLATFORMS')\n"
-    "jax.config.update('jax_platforms', p) if p else None\n"
     "d=jax.devices()\n"
     f"_w=os.environ.get({WEDGE_ENV!r})\n"
     f"if _w is not None and _w in ({WEDGE_ALL!r}, os.environ.get("

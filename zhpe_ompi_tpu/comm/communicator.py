@@ -32,7 +32,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import compat
 from ..core import attributes
 from ..core import errhandler as errh
 from ..core import errors
@@ -456,7 +455,7 @@ class Communicator(errh.HasErrhandler, attributes.AttrHost):
             in_specs = P(self.axis)
         if out_specs is None:
             out_specs = P(self.axis)
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )

@@ -27,8 +27,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-
-from .. import compat
 from jax import lax
 
 from .. import ops as zops
@@ -295,7 +293,7 @@ def make_train_step(cfg: Config, mesh, dp_comm, tp_comm, sp_comm=None,
     sp_ax = sp_comm.axis if sp_comm is not None else None
     data_spec = P(dp_comm.axis, sp_ax)
     step = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             spmd_step,
             mesh=mesh,
             in_specs=(param_specs, data_spec, data_spec),
@@ -355,7 +353,7 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
     sp_ax = sp_comm.axis if sp_comm is not None else None
     data_spec = P(dp_comm.axis, sp_ax)
     grad_step = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             spmd_grads, mesh=mesh,
             in_specs=(param_specs, data_spec, data_spec),
             out_specs=(param_specs, P()),

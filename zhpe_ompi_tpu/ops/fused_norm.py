@@ -1,7 +1,6 @@
 """Fused layernorm Pallas kernel (fwd + bwd) — the round-4 MFU lever.
 
-The round-3 cap analysis (bench.py docstring, benchmarks/mfu_sweep.py)
-measured the steady-state plateau at 38-39% MFU and named the HBM-bound
+The round-3 cap analysis (bench.py docstring) measured the steady-state plateau at 38-39% MFU and named the HBM-bound
 segments between matmuls: the f32 layernorms are pure bandwidth — XLA
 computes the row statistics and the normalize as separate passes with an
 f32 upcast materialized in between, so each LN costs ~3x the minimal
@@ -18,8 +17,8 @@ scratch and written at the last step.
 
 The reference has no analog (its hot loops are C over the wire,
 SURVEY.md §2); this is TPU-only ground.  Reference numerics live in
-``ln_reference`` — the models import the dispatcher, which falls back to
-the reference off-TPU exactly like flash attention does.
+``ln_reference`` — the models import the dispatcher, which uses the
+reference off-TPU exactly like flash attention does.
 """
 
 from __future__ import annotations
@@ -29,6 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .flash_attention import on_tpu
 
 _EPS = 1e-5
 
@@ -174,73 +175,26 @@ _ln_pallas.defvjp(_ln_vjp_fwd, _ln_vjp_bwd)
 # ------------------------------------------------------------- dispatcher
 
 
-def _on_tpu() -> bool:
-    dev0 = jax.devices()[0]
-    kind = getattr(dev0, "device_kind", "").lower()
-    return dev0.platform == "tpu" or any(
-        t in kind for t in ("tpu", "v4", "v5", "v6", "trillium")
-    )
-
-
-_kernel_ok: bool | None = None
-_warned = False
-
-
-def _warn_fallback(reason: str) -> None:
-    global _warned
-    if not _warned:
-        import warnings
-
-        warnings.warn(
-            f"Pallas fused-layernorm kernel unavailable ({reason}); "
-            f"using the jnp reference", stacklevel=3,
-        )
-        _warned = True
-
-
-def _kernel_available() -> bool:
-    global _kernel_ok
-    if _kernel_ok is None:
-        import numpy as np
-
-        try:
-            x = jnp.ones((256, 256), jnp.bfloat16)
-            out = _ln_pallas(x, jnp.ones((256,), jnp.float32), 128, False)
-            _kernel_ok = bool(np.isfinite(np.asarray(out)).all())
-            if not _kernel_ok:
-                _warn_fallback("probe produced non-finite output")
-        except Exception as e:  # noqa: BLE001
-            _warn_fallback(type(e).__name__)
-            _kernel_ok = False
-    return _kernel_ok
-
-
 def layer_norm(x, g, block_rows: int = 256, interpret: bool = False,
                force: bool = False):
-    """Layernorm with gain over the last axis; Pallas one-pass kernel on
-    TPU, reference jnp elsewhere.  ``force=True`` routes through the
-    kernel anywhere (interpreted off-TPU, for tests); rows that do not
-    tile the block fall back to the reference (the kernels want whole
-    tiles, as flash does)."""
+    """Layernorm with gain over the last axis.
+
+    Dispatch, on the input and the platform only: rows that do not tile
+    the block, or a width ``d`` that is not a multiple of 128 (the TPU
+    lane width), take the jnp reference — the kernels want whole tiles,
+    as flash does.  Otherwise the one-pass Pallas kernel runs on TPU, or
+    anywhere under ``force``/``interpret`` (interpreted off-TPU, for
+    tests); a kernel that fails to lower raises.  Elsewhere the jnp
+    reference runs."""
     d = x.shape[-1]
     n = 1
     for s in x.shape[:-1]:
         n *= s
     block = min(block_rows, n)
-    if n % block or d % 128 or d < 128:
+    if n % block or d % 128:
         return ln_reference(x, g)
-    x2 = x.reshape(n, d)
-    on_tpu = _on_tpu()
-    if force:
-        y = _ln_pallas(x2, g, block, interpret or not on_tpu)
-        return y.reshape(x.shape)
-    if not (on_tpu or interpret):
+    tpu = on_tpu()
+    if not (tpu or force or interpret):
         return ln_reference(x, g)
-    if on_tpu and not interpret and not _kernel_available():
-        return ln_reference(x, g)
-    try:
-        y = _ln_pallas(x2, g, block, interpret)
-        return y.reshape(x.shape)
-    except Exception as e:  # noqa: BLE001 - lowering/executable failure
-        _warn_fallback(f"{type(e).__name__} at shape {tuple(x.shape)}")
-        return ln_reference(x, g)
+    y = _ln_pallas(x.reshape(n, d), g, block, interpret or not tpu)
+    return y.reshape(x.shape)

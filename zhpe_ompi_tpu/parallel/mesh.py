@@ -12,8 +12,10 @@ btl/ofi endpoint set; host-loopback CPU devices are the btl/self+sm analog
 from __future__ import annotations
 
 import contextlib
+import json
 import os
 import threading
+import time
 import weakref
 
 import numpy as np
@@ -45,7 +47,8 @@ mca_var.register(
     "a region that outlives device_probe_deadline triggers a killable-"
     "child probe (tiny psum over the mesh, coll/tpu.PROBE_SRC); a "
     "missed probe classifies a typed cause=\"device\" fault into the "
-    "job's FailureState.  Off by default — probes cost a subprocess",
+    "job's FailureState.  Off by default — a probe costs a subprocess "
+    "(a thread of this process on TPU, where the chip is this process's)",
     type=bool,
 )
 mca_var.register(
@@ -143,15 +146,65 @@ def make_mesh(axis_sizes: dict[str, int], devices=None) -> Mesh:
 # -- device liveness probe (the fault loop's device half) -------------------
 
 
+def _holds_tpu() -> bool:
+    """Whether this process drives a TPU.  The chip then belongs to this
+    process, and a probe child could not reach it."""
+    return jax.default_backend() == "tpu"
+
+
+def _psum_i(v):
+    return jax.lax.psum(v, "i")
+
+
+def _probe_in_process(deadline: float,
+                      rank: int | None) -> tuple[str, str]:
+    """The TPU form of the probe: the same tiny psum as
+    ``coll/tpu.PROBE_SRC``, run by this process on a daemon thread and
+    bounded by ``deadline``.  A wedged collective strands that thread,
+    never the caller; the outcomes are the child's ("ok", "deadline",
+    "error")."""
+    from ..coll import tpu as coll_tpu
+
+    out: dict = {}
+
+    def body():
+        try:
+            wedge = os.environ.get(coll_tpu.WEDGE_ENV)
+            if wedge is not None and wedge in (
+                    coll_tpu.WEDGE_ALL, "" if rank is None else str(rank)):
+                time.sleep(3600)  # the injected wedge, as in the child
+            d = jax.devices()
+            s = jax.pmap(_psum_i, axis_name="i")(
+                jax.numpy.arange(float(len(d))))
+            out["ok"] = json.dumps({"n": len(d), "platform": d[0].platform,
+                                    "psum": float(s[0])})
+        except Exception as e:  # noqa: BLE001 - the structured "error"
+            out["error"] = f"{type(e).__name__}: {e}"
+
+    t = threading.Thread(target=body, daemon=True,
+                         name="device-probe-inproc")
+    t.start()
+    t.join(deadline)
+    if t.is_alive():
+        return "deadline", (
+            f"in-process probe hit its deadline ({deadline:.0f}s)")
+    if "error" in out:
+        return "error", out["error"]
+    return "ok", out["ok"]
+
+
 def probe_device_plane(timeout: float | None = None,
                        deadline: float | None = None,
                        env: dict | None = None,
                        rank: int | None = None) -> tuple[str, str]:
-    """One killable-child device liveness probe: the tiny deadline-
-    bounded psum (``coll/tpu.PROBE_SRC``) through the shared
-    ``utils/deadline`` idiom — exactly the machinery ``bench.py`` uses
-    for its backend probe, so a wedged ``jax.devices()`` OR a wedged
-    collective dies from the inside at the child's internal watchdog.
+    """One device liveness probe: the tiny deadline-bounded psum
+    (``coll/tpu.PROBE_SRC``).  Off-TPU it runs in a killable child
+    through the shared ``utils/deadline`` idiom, so a wedged
+    ``jax.devices()`` OR a wedged collective dies from the inside at the
+    child's internal watchdog.  On a TPU the chip belongs to this
+    process, so the probe runs here (:func:`_probe_in_process`) and
+    never starts a child that would need the chip; ``timeout`` and
+    ``env`` then do not apply.
 
     Returns the structured ``(kind, detail)``: "ok" (detail = device
     JSON), "hung", "deadline", "error".  Counts ``device_probe_rounds``
@@ -164,16 +217,20 @@ def probe_device_plane(timeout: float | None = None,
         if timeout is None else float(timeout)
     deadline = float(mca_var.get("device_probe_deadline", 12.0)) \
         if deadline is None else float(deadline)
-    if rank is not None:
-        # scope the wedge-injection hook: the child wedges only when
-        # the hook names THIS rank (or "1" = the whole process) — a
-        # healthy rank sharing the process must get a healthy answer
-        env = dict(os.environ if env is None else env)
-        env[coll_tpu.PROBE_RANK_ENV] = str(int(rank))
     spc.record("device_probe_rounds")
     sp = ztrace.begin(ztrace.DEVICE_PROBE, -1) if ztrace.active else None
-    kind, detail = deadline_mod.run_probe(
-        coll_tpu.PROBE_SRC, timeout, deadline, env=env)
+    if _holds_tpu():
+        kind, detail = _probe_in_process(deadline, rank)
+    else:
+        if rank is not None:
+            # scope the wedge-injection hook: the child wedges only when
+            # the hook names THIS rank (or "all" = the whole process) —
+            # a healthy rank sharing the process must get a healthy
+            # answer
+            env = dict(os.environ if env is None else env)
+            env[coll_tpu.PROBE_RANK_ENV] = str(int(rank))
+        kind, detail = deadline_mod.run_probe(
+            coll_tpu.PROBE_SRC, timeout, deadline, env=env)
     if kind in ("hung", "deadline"):
         spc.record("device_probe_misses")
     if sp is not None:
@@ -196,8 +253,9 @@ class DeviceLivenessProbe:
             loss = step(params, batch)   # may wedge mid-psum
 
     A region that outlives ``device_probe_deadline`` triggers one
-    killable-child probe from the watchdog thread (the region itself
-    cannot be killed — the XLA dispatch holds the caller's thread):
+    probe (:func:`probe_device_plane`) from the watchdog thread (the
+    region itself cannot be killed — the XLA dispatch holds the
+    caller's thread):
 
     - probe MISSED ("hung"/"deadline"): the local device plane is
       wedged — classify a typed ``cause="device"`` fault for THIS rank

@@ -3107,16 +3107,20 @@ class TcpProc(errh.HasErrhandler, ulfm.UlfmEndpointAPI, HostCollectives,
             # — the per-socket-FIFO ordering argument, restored across
             # the transport split
             self._sm_quiesce(min(deadline, time.monotonic() + 5.0))
-        if self.ft_state is not None and not self._ft_dead:
+        if self.ft_state is not None and not self._ft_dead \
+                and not self.ft_state.is_failed(self.rank):
             # orderly departure: tell the survivors we are LEAVING, so
             # their detectors reconfigure the ring instead of suspecting
             # us via missed beats (cause="goodbye", pre-acknowledged:
             # never a detector false positive, and never a pending gate
             # on survivors' wildcard receives — finalize skew is not a
             # crash) — the goodbye the crash paths (sever/mute)
-            # deliberately omit.  Per-socket FIFO puts the goodbye after
-            # every frame already sent, so no delivered message is
-            # reclassified as lost.
+            # deliberately omit, and so does a rank its own device
+            # probe classified failed: a BYE would mark it departed on
+            # peers the typed device notice has not reached yet, and
+            # "goodbye" is never refined to the root cause.  Per-socket
+            # FIFO puts the goodbye after every frame already sent, so
+            # no delivered message is reclassified as lost.
             goodbye = dss.pack(self.rank, 0, ulfm.FT_BYE_CID, 0,
                                [self.rank])
             # sm peers get the goodbye THROUGH their ring: it then

@@ -43,7 +43,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from ..core import errors
 from ..osc.spmd_window import DeviceWindow
 from .memheap import SymmetricHeapAllocator
@@ -396,7 +395,7 @@ class DeviceHeap:
             return new, (jnp.zeros((1, 1)) if out is None else out)
 
         in_specs = ([P(ax)] * len(keys),) + tuple(P(ax) for _ in args)
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             body, mesh=self.comm.mesh,
             in_specs=in_specs,
             out_specs=([P(ax)] * len(keys), P(ax)),

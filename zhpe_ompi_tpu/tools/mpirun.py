@@ -270,6 +270,30 @@ def resize_dvm(dvm: str, job_id: str, n: int,
         client.close()
 
 
+def tpu_chips() -> int:
+    """TPU chips this host exposes, counted from their device nodes
+    without loading JAX: ``/dev/accel<n>`` (v4, v5p) or the VFIO groups
+    ``/dev/vfio/<n>`` (v5e and later)."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) or \
+        len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def refuse_shared_chips(n_local: int, env=None) -> None:
+    """A TPU chip belongs to one process: the first rank that touches
+    JAX takes every chip of the host, and the next one fails or hangs.
+    So on a TPU host more than one local rank is refused, loudly, unless
+    the ranks are pinned to the CPU (``JAX_PLATFORMS=cpu``)."""
+    env = os.environ if env is None else env
+    if n_local > 1 and env.get("JAX_PLATFORMS") != "cpu" and tpu_chips():
+        raise RuntimeError(
+            f"zmpirun: {n_local} ranks on a TPU host would contend for "
+            f"its {tpu_chips()} chip(s): run one rank per host (one "
+            f"process drives all of its chips), or set JAX_PLATFORMS=cpu "
+            f"for host-plane ranks")
+
+
 def launch_mpmd(apps: list[tuple[int, list[str]]], host: str = "127.0.0.1",
                 mca: list[tuple[str, str]] | None = None,
                 timeout: float | None = None, tag_output: bool = True,
@@ -281,6 +305,7 @@ def launch_mpmd(apps: list[tuple[int, list[str]]], host: str = "127.0.0.1",
     if not apps or any(n < 1 for n, _ in apps):
         raise ValueError("zmpirun: every app context needs -n >= 1")
     n = sum(cnt for cnt, _ in apps)
+    refuse_shared_chips(n)
     stdout = stdout if stdout is not None else sys.stdout
     stderr = stderr if stderr is not None else sys.stderr
     port, coord_srv = _start_coordinator(host, n, timeout or 120.0)

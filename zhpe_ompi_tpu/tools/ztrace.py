@@ -273,7 +273,7 @@ def _pair_messages(spans: list[dict]) -> list[dict]:
 
 
 def _classify_pair(pair: dict) -> str:
-    """The mpiP/Vampir taxonomy on one message: the receiver posted
+    """The mpiP/Vampir classification of one message: the receiver posted
     before the message arrived → it WAITED on a late sender; the
     message arrived (parked unexpected) before the post → late
     receiver; otherwise balanced."""

@@ -1,6 +1,6 @@
 """Deadline-armed killable probes — the one probe idiom.
 
-``bench.py``'s backend probe (rounds 4/5) established the shape: any
+The device plane's liveness probe needs this shape: any
 check that can WEDGE — a hung ``jax.devices()``, a TPU participant
 stuck mid-``psum`` — must run where it can be killed (a subprocess),
 carry its own HARD internal deadline (a watchdog thread inside the
@@ -9,10 +9,10 @@ if the outer kill is delayed), and report a STRUCTURED outcome so no
 caller ever sniffs free-form stderr (a gRPC DEADLINE_EXCEEDED inside an
 ordinary error must never be mistaken for a wedged probe).
 
-This module is that idiom, shared: ``bench.py`` re-points its backend
-probe here, and the device liveness probe (``parallel/mesh.py`` /
-``coll/tpu.py``) arms the same machinery around device collectives.
-Two pieces:
+This module is that idiom; the device liveness probe (``parallel/mesh.py``
+/ ``coll/tpu.py``) arms it around device collectives off-TPU (on a TPU
+the chip belongs to the probing process, so the probe runs in-process
+there).  Two pieces:
 
 - :func:`run_probe` — one killable child probe.  Returns ``(kind,
   detail)`` with kind in ``"ok"`` (child printed its result), ``"hung"``
@@ -44,7 +44,7 @@ from typing import Callable
 
 #: exit code of a child whose INTERNAL watchdog expired — outside the
 #: posix signal range and distinct from common tool rcs (the structured
-#: "deadline" outcome; bench.py shipped this value first)
+#: "deadline" outcome)
 PROBE_DEADLINE_RC = 3
 
 #: environment variable the child preamble reads its deadline from
