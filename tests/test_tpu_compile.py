@@ -10,19 +10,23 @@ The topology is described in a module fixture, never at import: only one
 process may load libtpu, and the test workers import every test file.
 """
 
+import base64
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P, \
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 HBM_BYTES = 16 << 30  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     # The TPU compiler's threads start with libtpu and inherit this
@@ -43,10 +47,15 @@ def one_chip():
             # a described compile cannot be read back from the
             # persistent cache without a chip: keep it out
             jax.config.update("jax_enable_compilation_cache", False)
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_on)
         os.sched_setaffinity(0, cpus)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile_for_chip(lowered):
@@ -120,3 +129,140 @@ def test_train_step_compiles(one_chip, monkeypatch):
     tok = jax.ShapeDtypeStruct((16, cfg.seq), jnp.int32,
                                sharding=NamedSharding(mesh, P("dp")))
     _compile_for_chip(step.lower(params, tok, tok))
+
+
+# ------------------------------------------------- names a profile reads
+
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "layernorm_fwd",
+           "layernorm_bwd")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = .*? ([a-z][a-z0-9-]*)\(")
+_COLLECTIVE = re.compile(r"^(all-reduce|all-gather|reduce-scatter|"
+                         r"collective-permute|all-to-all)(-start)?$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_METADATA = re.compile(r', metadata=\{(?:[^}"]|"[^"]*")*\}'
+                       r"|^(?:FileNames|FunctionNames|FileLocations|"
+                       r"StackFrames)\n(?:.+\n)*", re.M)
+
+
+def _compile_optax_step(topo, monkeypatch):
+    """The flagship AdamW step (gradient and update programs as one jit) at
+    a small size over a dp2 x tp2 mesh of the described chips, kernels on;
+    returns the optimized HLO text."""
+    import optax
+
+    import zhpe_ompi_tpu as zmpi
+    from zhpe_ompi_tpu.models import transformer as tfm
+    from zhpe_ompi_tpu.ops import flash_attention, fused_norm
+
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(fused_norm, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("dp", "tp"))
+    cfg = tfm.Config(vocab=512, d_model=256, n_heads=2, d_ff=512,
+                     n_layers=2, seq=128, remat=True, ce_chunk=256)
+    dp_comm = zmpi.Communicator(mesh, "dp", name="t_dp")
+    tp_comm = zmpi.Communicator(mesh, "tp", name="t_tp")
+    init_opt, step, specs = tfm.make_train_step_optax(
+        cfg, mesh, dp_comm, tp_comm, optimizer=optax.adamw(1e-3))
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    params = {
+        k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                sharding=NamedSharding(mesh, specs[k]))
+        for k, v in shapes.items()
+    }
+    opt = jax.eval_shape(init_opt, params)
+    tok = jax.ShapeDtypeStruct((4, cfg.seq), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp")))
+    return jax.jit(step).lower(params, opt, tok, tok).compile().as_text()
+
+
+def _ops(text):
+    """(instruction name, opcode, op_name) of each instruction."""
+    out = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            n = _OP_NAME.search(line)
+            out.append((m.group(1), m.group(2), n.group(1) if n else ""))
+    return out
+
+
+@pytest.fixture(scope="module")
+def optax_step_hlo(topo):
+    with pytest.MonkeyPatch.context() as mp:
+        return _compile_optax_step(topo, mp)
+
+
+@pytest.mark.parametrize("scope", ["zmpi.attn", "zmpi.mlp", "zmpi.loss"])
+def test_model_scopes_name_forward_and_backward(optax_step_hlo, scope):
+    # a scope reads "zmpi.attn/..." or, under a transform of the whole
+    # scope, "transpose(jvp(zmpi.loss))/..."
+    names = [n for _, _, n in _ops(optax_step_hlo)
+             if re.search(re.escape(scope) + r"[/)]", n)]
+    assert any("transpose(" not in n for n in names), f"no forward {scope}"
+    assert any("transpose(" in n for n in names), f"no backward {scope}"
+
+
+def test_optimizer_scope_names_the_update(optax_step_hlo):
+    assert any("zmpi.optimizer/" in n for _, _, n in _ops(optax_step_hlo))
+
+
+def test_every_collective_names_its_communicator(optax_step_hlo):
+    """Each collective carries ``zmpi.coll.<axis>.<name>.<op>``, and the
+    axis is the one its replica groups run over."""
+    groups = {"tp": "{{0,1},{2,3}}", "dp": "{{0,2},{1,3}}"}
+    lines = {m.group(1): line for line in optax_step_hlo.splitlines()
+             if (m := _INSTR.match(line))}
+    axes = set()
+    for instr, op, name in _ops(optax_step_hlo):
+        if not _COLLECTIVE.match(op):
+            continue
+        m = re.search(r"zmpi\.coll\.(\w+)\.t_\1\.allreduce/", name)
+        assert m, f"{instr} carries no communicator scope: {name}"
+        assert f"replica_groups={groups[m.group(1)]}" in lines[instr]
+        axes.add(m.group(1))
+    assert axes == {"dp", "tp"}
+
+
+def test_kernels_are_named(optax_step_hlo):
+    """Every Pallas call is an instruction named for its kernel, the name
+    a profile's op events carry."""
+    found = []
+    for line in optax_step_hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            instr = _INSTR.match(line).group(1)
+            hits = [k for k in KERNELS if k in instr]
+            assert len(hits) == 1, f"kernel instruction {instr}"
+            found.append(hits[0])
+    assert set(found) == set(KERNELS)
+
+
+def test_scopes_change_only_metadata(topo, optax_step_hlo, monkeypatch):
+    """Without the scopes the compiled step is the same program: the same
+    instructions, fusions, layouts, order and kernels, once the metadata
+    is dropped (with the source tables it points into) and the
+    instructions are renamed in order of appearance."""
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _compile_optax_step(topo, monkeypatch)
+    assert "zmpi." not in bare and "zmpi." in optax_step_hlo
+
+    from jaxlib.mlir import ir
+
+    def kernel(m):
+        # a kernel's body is Mosaic bytecode that carries its own source
+        # locations, the scopes among them: compare it without them
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        body = ir.Module.parse(base64.b64decode(m.group(1)), context=ctx)
+        return body.operation.get_asm(enable_debug_info=False)
+
+    def structure(text):
+        text = _METADATA.sub("", text)
+        text = re.sub(r'"body":"([^"]*)"', kernel, text)
+        names = {}
+        return re.sub(r"%[\w.-]+",
+                      lambda m: names.setdefault(m.group(0),
+                                                 f"%{len(names)}"), text)
+
+    assert structure(bare) == structure(optax_step_hlo)

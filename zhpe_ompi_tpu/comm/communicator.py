@@ -355,9 +355,16 @@ class Communicator(errh.HasErrhandler, attributes.AttrHost):
         # ompi/mpi/c/send.c:37-39): tools see the call before the MCA path
         from ..tools import pmpi
 
-        if pmpi.active():
-            return pmpi.dispatch(opname, self, fn, args, kwargs)
-        return fn(self, *args, **kwargs)
+        with self._scope(opname):
+            if pmpi.active():
+                return pmpi.dispatch(opname, self, fn, args, kwargs)
+            return fn(self, *args, **kwargs)
+
+    def _scope(self, opname: str):
+        """Names the device ops of one call in their HLO metadata,
+        ``zmpi.coll.<axis>.<name>.<op>``, whichever component serves it,
+        so a profile gives each collective to its communicator."""
+        return jax.named_scope(f"zmpi.coll.{self.axis}.{self.name}.{opname}")
 
     def set_info(self, info) -> None:
         """MPI_Comm_set_info: replace the hint set."""
@@ -430,20 +437,23 @@ class Communicator(errh.HasErrhandler, attributes.AttrHost):
         (rank-offset).  With wrap=False the ends get zeros (MPI_PROC_NULL)."""
         from ..pt2pt import spmd as _spmd
 
-        return _spmd.shift(self, x, offset, wrap=wrap)
+        with self._scope("shift"):
+            return _spmd.shift(self, x, offset, wrap=wrap)
 
     def permute(self, x, dest_of: list[int]):
         """General static sendrecv: dest_of[i] is where comm rank i's buffer
         goes (-1 = sends nowhere); ranks nobody targets receive zeros."""
         from ..pt2pt import spmd as _spmd
 
-        return _spmd.sendrecv(self, x, dest_of)
+        with self._scope("permute"):
+            return _spmd.sendrecv(self, x, dest_of)
 
     def ppermute(self, x, pairs: list[tuple[int, int]]):
         """Comm-relative collective permute (the BTL of the SPMD plane)."""
         from ..pt2pt import spmd as _spmd
 
-        return _spmd.ppermute(self, x, pairs)
+        with self._scope("ppermute"):
+            return _spmd.ppermute(self, x, pairs)
 
     # -- host-side execution helper --------------------------------------
 

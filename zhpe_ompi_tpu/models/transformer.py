@@ -133,39 +133,44 @@ def forward_hidden(params: dict, tokens, cfg: Config, tp_comm=None,
 
     def block(x, layer):
         wqkv, wo, w1, w2, g1, g2 = layer
-        h = _ln(x, g1, cfg.fused_ln)
-        if tp_comm is not None:
-            h = f_identity(tp_comm, h)
-        qkv = jnp.einsum("bsd,dce->bsce", h, wqkv.astype(dtype))
-        q = qkv[:, :, 0].reshape(B, S, n_heads_local, hd)
-        k = qkv[:, :, 1].reshape(B, S, n_heads_local, hd)
-        v = qkv[:, :, 2].reshape(B, S, n_heads_local, hd)
-        if sp_comm is not None:
-            if cfg.zigzag_sp:
-                from .ring_attention import ring_attention_zigzag
+        # the scopes name each op's HLO metadata (forward, backward and
+        # remat alike) so a profile splits the step by layer part; they
+        # end before the tp all-reduce, which the communicator names
+        with jax.named_scope("zmpi.attn"):
+            h = _ln(x, g1, cfg.fused_ln)
+            if tp_comm is not None:
+                h = f_identity(tp_comm, h)
+            qkv = jnp.einsum("bsd,dce->bsce", h, wqkv.astype(dtype))
+            q = qkv[:, :, 0].reshape(B, S, n_heads_local, hd)
+            k = qkv[:, :, 1].reshape(B, S, n_heads_local, hd)
+            v = qkv[:, :, 2].reshape(B, S, n_heads_local, hd)
+            if sp_comm is not None:
+                if cfg.zigzag_sp:
+                    from .ring_attention import ring_attention_zigzag
 
-                o = ring_attention_zigzag(sp_comm, q, k, v)
+                    o = ring_attention_zigzag(sp_comm, q, k, v)
+                else:
+                    o = ring_attention(sp_comm, q, k, v, causal=True)
+                o = o.reshape(B, S, -1)
+            elif use_flash:
+                from ..ops.flash_attention import flash_attention
+
+                o = flash_attention(
+                    q, k, v, causal=True, force=cfg.flash is True
+                ).reshape(B, S, -1)
             else:
-                o = ring_attention(sp_comm, q, k, v, causal=True)
-            o = o.reshape(B, S, -1)
-        elif use_flash:
-            from ..ops.flash_attention import flash_attention
-
-            o = flash_attention(
-                q, k, v, causal=True, force=cfg.flash is True
-            ).reshape(B, S, -1)
-        else:
-            o = _attn(q, k, v).reshape(B, S, -1)
-        o = jnp.einsum("bse,ed->bsd", o, wo.astype(dtype))
+                o = _attn(q, k, v).reshape(B, S, -1)
+            o = jnp.einsum("bse,ed->bsd", o, wo.astype(dtype))
         if tp_comm is not None:
             o = g_allreduce(tp_comm, o)
         x = x + o
-        h = _ln(x, g2, cfg.fused_ln)
-        if tp_comm is not None:
-            h = f_identity(tp_comm, h)
-        u = jnp.einsum("bsd,df->bsf", h, w1.astype(dtype))
-        u = jax.nn.gelu(u)
-        d = jnp.einsum("bsf,fd->bsd", u, w2.astype(dtype))
+        with jax.named_scope("zmpi.mlp"):
+            h = _ln(x, g2, cfg.fused_ln)
+            if tp_comm is not None:
+                h = f_identity(tp_comm, h)
+            u = jnp.einsum("bsd,df->bsf", h, w1.astype(dtype))
+            u = jax.nn.gelu(u)
+            d = jnp.einsum("bsf,fd->bsd", u, w2.astype(dtype))
         if tp_comm is not None:
             d = g_allreduce(tp_comm, d)
         return x + d, None
@@ -216,7 +221,8 @@ def loss_fn(params, tokens, targets, cfg: Config, tp_comm=None, sp_comm=None):
     # loss body, bit-for-bit
     from ..ops.fused_ce import token_ce
 
-    return token_ce(x, emb, targets, cfg.ce_chunk)
+    with jax.named_scope("zmpi.loss"):
+        return token_ce(x, emb, targets, cfg.ce_chunk)
 
 
 # Parameters replicated over tp (everything else is tp-sharded).
@@ -364,12 +370,13 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
     init_opt_state = jax.jit(optimizer.init)
 
     def _apply(params, opt_state, grads):
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        # preserve storage dtype (apply_updates upcasts mixed dtypes)
-        new_params = jax.tree.map(
-            lambda new, old: new.astype(old.dtype), new_params, params
-        )
+        with jax.named_scope("zmpi.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+            # preserve storage dtype (apply_updates upcasts mixed dtypes)
+            new_params = jax.tree.map(
+                lambda new, old: new.astype(old.dtype), new_params, params
+            )
         return new_params, opt_state
 
     # donate the old params + optimizer state: callers thread both
@@ -383,8 +390,11 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
         k: NamedSharding(mesh, spec) for k, spec in param_specs.items()
     }
 
+    # host spans on the profiler's clock around the two dispatches, so an
+    # idle gap on the device can be told to belong to one or the other
     def step(params, opt_state, tokens, targets):
-        grads, loss = grad_step(params, tokens, targets)
+        with jax.profiler.TraceAnnotation("zmpi.train.grad"):
+            grads, loss = grad_step(params, tokens, targets)
         if dcn_proc is not None and dcn_proc.size > 1:
             from ..parallel import hybrid
 
@@ -425,7 +435,8 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
                 # keep the return contract uniform across modes: loss
                 # is always a jax scalar
                 loss = jnp.asarray(bundle["loss"])
-        new_params, opt_state = apply(params, opt_state, grads)
+        with jax.profiler.TraceAnnotation("zmpi.train.apply"):
+            new_params, opt_state = apply(params, opt_state, grads)
         return new_params, opt_state, loss
 
     return init_opt_state, step, param_specs

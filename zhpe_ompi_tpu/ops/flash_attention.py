@@ -169,6 +169,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(B, h, S, hd).transpose(0, 2, 1, 3), lse
 
@@ -325,6 +326,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     # k-major grid: swap the roles of axes 1/2 in the index maps
@@ -351,6 +353,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     def unfold(x):

@@ -82,6 +82,7 @@ def _ln_fwd(x2, g, block_rows: int, interpret: bool):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="layernorm_fwd",
     )(x2, g.reshape(1, d))
     return y, m, r
 
@@ -145,6 +146,7 @@ def _ln_bwd(x2, g, dy2, m, r, block_rows: int, interpret: bool):
         ],
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         interpret=interpret,
+        name="layernorm_bwd",
     )(x2, g.reshape(1, d), dy2, m, r)
     return dx, dg
 
