@@ -472,6 +472,52 @@ class TestTunedAutoPath:
         assert tuned.decide("gather", world, small) == "xla"
         assert tuned.decide("gather", world, large) == "binomial"
 
+    @pytest.mark.parametrize("size,op,tpu,forced,mib,want", [
+        (2, "sum", True, None, 64, "recursive_doubling"),  # one swap
+        (2, "sum", True, None, 65, "xla"),  # gradient-sized: in place
+        (4, "sum", True, None, 1, "xla"),
+        (2, "sum", False, None, 1, "xla"),
+        (2, "user", True, None, 1, "linear"),  # rank order kept
+        (2, "sum", True, "ring", 1, "ring"),   # the MCA override still wins
+    ], ids=["tpu2", "tpu2_large", "tpu4", "cpu2", "noncommutative",
+            "forced"])
+    def test_decide_two_rank_allreduce(self, world, fresh_vars, monkeypatch,
+                                       size, op, tpu, forced, mib, want):
+        """A two-rank commutative allreduce of up to 64 MiB on a TPU is one
+        swap (an asynchronous collective permute there); every other case
+        keeps its decision."""
+        from zhpe_ompi_tpu import ops as zops
+        from zhpe_ompi_tpu.coll import tuned
+        from zhpe_ompi_tpu.mca import var as mca_var
+
+        tuned._register_params()
+        if forced:
+            mca_var.set_var("coll_tuned_allreduce_algorithm", forced)
+        monkeypatch.setattr(tuned, "_on_tpu", lambda comm: tpu)
+        sub = world.split([i // size for i in range(N)])
+        zop = zops.SUM if op == "sum" else zops.create_op(
+            lambda a, b: a - b, commute=False, name="two_rank_sub")
+        x = jax.ShapeDtypeStruct((mib, 1024, 256), jnp.float32)
+        assert tuned.decide("allreduce", sub, x, zop) == want
+
+    def test_two_rank_swap_gives_the_psum(self, world, monkeypatch):
+        """The swap a TPU gets for a two-rank allreduce is one collective
+        permute, and gives what the psum gives, bit for bit."""
+        from zhpe_ompi_tpu.coll import tuned
+
+        sub = world.split([i // 2 for i in range(N)])
+        x = rng(17).normal(size=(N, 33)).astype(np.float32)
+
+        def allreduce(s):
+            return sub.allreduce(s, zmpi.SUM)
+
+        psum = run_spmd(sub, allreduce, x)
+        monkeypatch.setattr(tuned, "_on_tpu", lambda comm: True)
+        swap = run_spmd(sub, allreduce, x)
+        np.testing.assert_array_equal(swap, psum)
+        jaxpr = str(jax.make_jaxpr(lambda v: sub.run(allreduce, v))(x))
+        assert jaxpr.count("ppermute[") == 1 and "psum" not in jaxpr
+
     def test_large_scatter_auto_correct(self, world):
         """The auto path's binomial scatter must agree with the xla form."""
         per = 4096  # 8 ranks x 4096 f32 = 128 KB... below large; force via var

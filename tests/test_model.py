@@ -216,3 +216,149 @@ class TestOptaxStep:
                               jax.device_put(tok, dspec),
                               jax.device_put(tgt, dspec))
         assert np.isfinite(float(loss3)) and float(loss3) < float(loss)
+
+
+# ------------------------------------- the two-chunk tensor-parallel loop
+
+
+def _sub_jaxprs(value):
+    if hasattr(value, "eqns"):
+        yield value
+    elif hasattr(value, "jaxpr") and hasattr(value.jaxpr, "eqns"):
+        yield value.jaxpr
+    elif isinstance(value, (list, tuple)):
+        for v in value:
+            yield from _sub_jaxprs(v)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in _sub_jaxprs(v):
+                yield from _eqns(sub)
+
+
+def _layer_loop(cfg, mesh_shape, batch, sp=False):
+    """The forward's layer scan in the traced ``loss_fn`` on a mesh of
+    the given (dp, tp) shape, ``tp`` 0 meaning no tp communicator (``sp``
+    adds an sp axis of 2): the shapes of its carry and the leading
+    dimension of each flash forward call in its body."""
+    dp, tp = mesh_shape
+    axes = ("dp", "tp", "sp")[:3 if sp else 2]
+    shape = (dp, max(tp, 1), 2)[:len(axes)]
+    n = int(np.prod(shape))
+    mesh = Mesh(np.asarray(jax.devices()[:n]).reshape(shape), axes)
+    name = f"ck{dp}{tp}{int(sp)}{batch}"
+    tp_comm = zmpi.Communicator(mesh, "tp", name=name + "tp") if tp else None
+    sp_comm = zmpi.Communicator(mesh, "sp", name=name + "sp") if sp else None
+    specs = tfm._param_specs("tp" if tp else None)
+    data = P("dp", "sp") if sp else P("dp")
+    fn = jax.shard_map(
+        lambda p, tok, tgt: tfm.loss_fn(p, tok, tgt, cfg, tp_comm, sp_comm),
+        mesh=mesh, in_specs=(specs, data, data), out_specs=P(),
+        check_vma=False)
+    params = jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.key(0)))
+    tok = jax.ShapeDtypeStruct((batch, cfg.seq), jnp.int32)
+    jaxpr = jax.make_jaxpr(fn)(params, tok, tok).jaxpr
+    (loop,) = [e for e in _eqns(jaxpr) if e.primitive.name == "scan"
+               and e.params["length"] == cfg.n_layers]
+    k, c = loop.params["num_consts"], loop.params["num_carry"]
+    carry = [v.aval.shape for v in loop.invars[k:k + c]]
+    flash = [e.invars[0].aval.shape[0]
+             for e in _eqns(loop.params["jaxpr"].jaxpr)
+             if e.primitive.name == "pallas_call"
+             and "flash_fwd" in str(e.params.get("name"))]
+    return carry, flash
+
+
+@pytest.mark.parametrize("mesh_shape,batch,sp,chunks", [
+    ((2, 2), 8, False, 2),   # tp, even local batch: two chunks
+    ((1, 2), 3, False, 1),   # odd local batch
+    ((1, 2), 4, True, 1),    # sp (ring attention)
+    ((1, 0), 4, False, 1),   # no tp
+    ((1, 4), 4, False, 1),   # tp over four ranks: no swap to overlap
+], ids=["tp_even", "tp_odd", "tp_sp", "no_tp", "tp4"])
+def test_layer_loop_chunks(mesh_shape, batch, sp, chunks):
+    """The layer loop carries two half-batch chunks only with a two-rank
+    tp, no sp and an even local batch; otherwise one chunk at the full
+    local batch, with one flash call per layer (the one-chip cells'
+    program)."""
+    cfg = tfm.Config(vocab=61, d_model=16, n_heads=4, d_ff=32, n_layers=3,
+                     seq=8, dtype=jnp.float32, flash=None if sp else True)
+    carry, flash = _layer_loop(cfg, mesh_shape, batch, sp)
+    local = batch // mesh_shape[0]
+    assert carry == [(local // chunks, cfg.seq // (2 if sp else 1),
+                      cfg.d_model)] * chunks
+    if not sp:
+        heads = cfg.n_heads // max(mesh_shape[1], 1)
+        assert flash == [local // chunks * heads] * chunks
+
+
+@pytest.mark.parametrize("mesh_shape,swap", [
+    ((2, 2), False), ((1, 2), False), ((2, 2), True),
+], ids=["dp2tp2", "dp1tp2", "dp2tp2_swap"])
+def test_two_chunk_tp_matches_one_chunk(cfg, monkeypatch, mesh_shape, swap):
+    """The two-chunk tp loop gives the one-chunk loop's loss and every
+    gradient leaf, to f32 rounding (only the order in which the weight
+    gradients' chunk products are summed differs); with ``swap`` its
+    all-reduces are the swaps a TPU gets, against the one-chunk psums."""
+    from zhpe_ompi_tpu.coll import tuned
+
+    dp, tp = mesh_shape
+    mesh = Mesh(np.asarray(jax.devices()[:dp * tp]).reshape(dp, tp),
+                ("dp", "tp"))
+    dp_comm = zmpi.Communicator(mesh, "dp", name=f"two_dp{dp}{swap:d}")
+    tp_comm = zmpi.Communicator(mesh, "tp", name=f"two_tp{dp}{swap:d}")
+    specs = tfm._param_specs("tp")
+    params = tfm.init_params(cfg, jax.random.PRNGKey(4))
+    tokens, targets = _data(cfg)
+
+    def loss_and_grads():
+        def spmd(p, tok, tgt):
+            loss, grads = jax.value_and_grad(
+                lambda q: tfm.loss_fn(q, tok, tgt, cfg, tp_comm))(p)
+            return tfm._sync_grads(grads, loss, dp_comm, tp_comm, None,
+                                   dp, tp, 1)
+
+        return jax.jit(jax.shard_map(
+            spmd, mesh=mesh, in_specs=(specs, P("dp"), P("dp")),
+            out_specs=(specs, P()), check_vma=False))(params, tokens,
+                                                      targets)
+
+    assert tfm._tp_chunks(tokens.shape[0] // dp, tp_comm, None) == 2
+    with monkeypatch.context() as m:
+        m.setattr(tuned, "_on_tpu", lambda comm: swap)
+        assert tuned.decide("allreduce", tp_comm, tokens, zmpi.SUM) == (
+            "recursive_doubling" if swap else "xla")
+        grads2, loss2 = loss_and_grads()
+    monkeypatch.setattr(tfm, "_tp_chunks", lambda *a: 1)
+    grads1, loss1 = loss_and_grads()
+    np.testing.assert_allclose(float(loss2), float(loss1), rtol=1e-6)
+    for k in params:
+        np.testing.assert_allclose(np.asarray(grads2[k]),
+                                   np.asarray(grads1[k]), rtol=1e-5,
+                                   atol=1e-7, err_msg=k)
+
+
+def test_revoked_tp_comm_raises_in_loss_fn(cfg):
+    """The tp all-reduces of the (two-chunk) layer loop go through the
+    communicator's dispatch: a revoked tp communicator raises there,
+    through its errhandler."""
+    from zhpe_ompi_tpu.core import errhandler as errh
+    from zhpe_ompi_tpu.core import errors
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    tp_comm = zmpi.Communicator(mesh, "tp", name="revoked_tp")
+    tp_comm.set_errhandler(errh.ERRORS_RETURN)
+    tp_comm.revoke()
+    params = jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.key(0)))
+    tok = jax.ShapeDtypeStruct((8, cfg.seq), jnp.int32)
+    fn = jax.shard_map(
+        lambda p, t, g: tfm.loss_fn(p, t, g, cfg, tp_comm),
+        mesh=mesh, in_specs=(tfm._param_specs("tp"), P("dp"), P("dp")),
+        out_specs=P(), check_vma=False)
+    assert tfm._tp_chunks(4, tp_comm, None) == 2
+    with pytest.raises(errors.Revoked):
+        jax.make_jaxpr(fn)(params, tok, tok)

@@ -209,7 +209,8 @@ def test_optimizer_scope_names_the_update(optax_step_hlo):
 
 def test_every_collective_names_its_communicator(optax_step_hlo):
     """Each collective carries ``zmpi.coll.<axis>.<name>.<op>``, and the
-    axis is the one its replica groups run over."""
+    axis is the one its replica groups run over (a collective permute's
+    pairs, each within one group of the axis)."""
     groups = {"tp": "{{0,1},{2,3}}", "dp": "{{0,2},{1,3}}"}
     lines = {m.group(1): line for line in optax_step_hlo.splitlines()
              if (m := _INSTR.match(line))}
@@ -219,8 +220,17 @@ def test_every_collective_names_its_communicator(optax_step_hlo):
             continue
         m = re.search(r"zmpi\.coll\.(\w+)\.t_\1\.allreduce/", name)
         assert m, f"{instr} carries no communicator scope: {name}"
-        assert f"replica_groups={groups[m.group(1)]}" in lines[instr]
-        axes.add(m.group(1))
+        axis = m.group(1)
+        if op.startswith("collective-permute"):
+            pairs = re.search(r"source_target_pairs=\{(.*?)\}\}",
+                              lines[instr]).group(1)
+            within = [set(map(int, g.split(","))) for g in
+                      re.findall(r"\{([\d,]+)\}", groups[axis])]
+            for s, t in re.findall(r"\{(\d+),(\d+)", pairs):
+                assert any({int(s), int(t)} <= g for g in within), instr
+        else:
+            assert f"replica_groups={groups[axis]}" in lines[instr]
+        axes.add(axis)
     assert axes == {"dp", "tp"}
 
 
@@ -266,3 +276,48 @@ def test_scopes_change_only_metadata(topo, optax_step_hlo, monkeypatch):
                                                  f"%{len(names)}"), text)
 
     assert structure(bare) == structure(optax_step_hlo)
+
+
+_COMPUTE = re.compile(r" (?:fusion|convolution|custom-call)\(")
+
+
+def _computations(text):
+    """Each computation's instruction lines, in their scheduled order."""
+    comps, cur = [], None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.endswith("{"):
+            cur = []
+            comps.append(cur)
+        elif cur is not None and _INSTR.match(line):
+            cur.append(line)
+    return comps
+
+
+def test_tp_all_reduces_overlap_the_other_chunk(optax_step_hlo):
+    """The forward layer body issues four tp all-reduces, one per chunk
+    and layer part, each its own asynchronous transfer; and at least
+    three of them have the other chunk's kernels or matmuls scheduled
+    between their start and their done (the last one, at the body's end,
+    has nothing left beside it)."""
+    def tp_forward(line):
+        n = _OP_NAME.search(line)
+        return n and "zmpi.coll.tp." in n.group(1) \
+            and "transpose(" not in n.group(1)
+
+    (body,) = [c for c in _computations(optax_step_hlo)
+               if any(_COLLECTIVE.match(_INSTR.match(ln).group(2))
+                      and tp_forward(ln) and "/while/body/" in ln
+                      for ln in c)]
+    ops = [_INSTR.match(ln).groups() for ln in body]
+    starts = [i for i, (_, op) in enumerate(ops)
+              if _COLLECTIVE.match(op) and tp_forward(body[i])]
+    assert len(starts) == 4, [ops[i] for i in starts]
+    hidden = 0
+    for i in starts:
+        name, op = ops[i]
+        assert op.endswith("-start"), f"{name} is a blocking {op}"
+        (done,) = [j for j, ln in enumerate(body)
+                   if re.search(rf"-done\(%{re.escape(name)}\)", ln)]
+        hidden += any(_COMPUTE.search(ln) and "kind=kLoop" not in ln
+                      for ln in body[i + 1:done])
+    assert hidden >= 3

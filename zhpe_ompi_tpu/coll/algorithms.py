@@ -81,7 +81,8 @@ def allreduce_recursive_doubling(comm, x, op):
         recv = spmd.ppermute(
             comm, x, [(i, i ^ k) for i in range(p2)]
         )
-        x = _where(rank < p2, op(recv, x), x)
+        # every rank is below p2 when no tail was folded in
+        x = _where(rank < p2, op(recv, x), x) if extra else op(recv, x)
         k <<= 1
     if extra:
         recv = spmd.ppermute(comm, x, [(i, p2 + i) for i in range(extra)])

@@ -13,9 +13,12 @@ NBC_Sched_send/recv/op/copy/barrier primitives: sequential yields ARE the
 round barriers, and arbitrary Python between yields is the op/copy rounds.
 
 Device-plane nonblocking collectives are a platform non-problem by design:
-inside a jit trace every XLA collective is already asynchronous (the
-scheduler overlaps it with unrelated compute), and ``jax.Array`` IS the
-request handle — ``block_until_ready`` is Wait.  Documented in PARITY.md.
+inside a jit trace XLA's scheduler places each collective, and
+``jax.Array`` IS the request handle — ``block_until_ready`` is Wait.  On
+a TPU v5e a collective permute is asynchronous (a start and a done with
+unrelated compute between them) but an all-reduce is one blocking op, so
+the decision layer issues a two-rank allreduce there as one swap
+(:func:`zhpe_ompi_tpu.coll.tuned.decide`).  Documented in PARITY.md.
 
 All schedules run over the same endpoint surface as
 :mod:`zhpe_ompi_tpu.coll.host` (universe RankContext, TcpProc) and use its

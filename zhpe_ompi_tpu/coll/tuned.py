@@ -340,6 +340,19 @@ def profiles() -> dict[str, str]:
     }
 
 
+# Largest two-rank allreduce a TPU gets as a swap.  The swap holds the
+# partner's copy in a buffer of its own until the combine, which the
+# in-place all-reduce does not; at gradient sizes (hundreds of MB, several
+# in flight at once) that copy is HBM a full chip does not have.
+_SWAP_MAX_BYTES = 64 * 1024 * 1024
+
+
+def _on_tpu(comm) -> bool:
+    """Whether ``comm``'s mesh holds TPU devices (a described one too)."""
+    devices = getattr(getattr(comm, "mesh", None), "devices", None)
+    return devices is not None and devices.flat[0].platform == "tpu"
+
+
 def decide(opname: str, comm, x, op=None) -> str:
     """Pick an algorithm name for this call — all inputs static at trace
     time, mirroring coll_tuned_decision_fixed.c but at zero runtime cost."""
@@ -364,6 +377,12 @@ def decide(opname: str, comm, x, op=None) -> str:
     small = mca_var.get("coll_tuned_small_msg", _DEFAULT_SMALL)
     large = mca_var.get("coll_tuned_large_msg", _DEFAULT_LARGE)
     if opname == "allreduce":
+        if n == 2 and nbytes <= _SWAP_MAX_BYTES and _on_tpu(comm):
+            # one swap with the partner and a local combine moves what a
+            # two-rank ring moves; a TPU runs the swap (a collective
+            # permute) asynchronously, start and done with independent
+            # work between, where its all-reduce is one blocking op
+            return "recursive_doubling"
         if op is not None and op.xla_collective:
             return "xla"
         if nbytes < small:

@@ -8,7 +8,9 @@ drives MPI/NCCL:
 - **tp** (tensor parallel): attention heads and MLP hidden are sharded over
   the 'tp' mesh axis; partial sums after the output/down projections are
   combined with ``tp_comm.allreduce`` (the MPI_Allreduce hot path of
-  BASELINE.md, executed as XLA psum on ICI).
+  BASELINE.md, executed as XLA psum on ICI, or with two ranks on a TPU
+  as one asynchronous swap that the two-chunk layer loop of
+  ``forward_hidden`` overlaps with the other chunk).
 - **dp** (data parallel): gradients are averaged with ``dp_comm.allreduce``.
 - **sp** (sequence parallel / long context): ring attention over the 'sp'
   axis using ``comm.ppermute`` ring steps (see ring_attention.py).
@@ -103,6 +105,21 @@ from ..ops.flash_attention import attn_reference as _attn  # noqa: E402
 # flash kernel (ops/flash_attention.py) so fallback/backward can't diverge
 
 
+# Independent batch chunks the tensor-parallel layer loop carries: two is
+# the least count that gives each chunk's all-reduces the other chunk's
+# matmuls to hide behind; more would shrink the matmuls and add per-call
+# latency.  Fixed, not configurable.
+TP_CHUNKS = 2
+
+
+def _tp_chunks(batch: int, tp_comm, sp_comm) -> int:
+    """Chunks the layer loop carries (see :func:`forward_hidden`)."""
+    if tp_comm is None or sp_comm is not None or batch % TP_CHUNKS \
+            or getattr(tp_comm, "uniform_size", None) != 2:
+        return 1
+    return TP_CHUNKS
+
+
 def forward_hidden(params: dict, tokens, cfg: Config, tp_comm=None,
                    sp_comm=None):
     """Forward pass on one device's shard, up to the final layernorm
@@ -117,6 +134,20 @@ def forward_hidden(params: dict, tokens, cfg: Config, tp_comm=None,
     `sp_comm` (sequence parallel / long context): tokens arrive sequence-
     sharded over the 'sp' axis and attention runs as ring attention over
     the framework's ppermute ring (models/ring_attention.py).
+
+    With a two-rank `tp_comm` and no `sp_comm`, an even local batch runs
+    the layer loop as two independent half-batch chunks
+    (:data:`TP_CHUNKS`), split after the embedding and joined before the
+    final layernorm; each chunk runs the whole block.  Every tp all-reduce
+    then has the other chunk's matmuls and attention beside it with no data
+    dependency.  On a TPU the communicator issues a two-rank allreduce as
+    one asynchronous swap (:func:`zhpe_ompi_tpu.coll.tuned.decide`), so the
+    scheduler overlaps the two: chunk a's all-reduce with chunk b's
+    matmuls, in the forward and, by autodiff, in the backward.  Per row the
+    math is the block's; weight gradients sum the chunks' partial products.
+    Without tp, with a wider tp (no swap to overlap), with sp (its ring
+    would run twice) or with an odd local batch the loop runs one chunk,
+    as before.
     """
     dtype = cfg.dtype
     x = params["embed"].astype(dtype)[tokens]  # (B, S_local, D)
@@ -130,9 +161,11 @@ def forward_hidden(params: dict, tokens, cfg: Config, tp_comm=None,
     # flash dispatch: auto picks per-platform inside flash_attention;
     # flash=True forces the kernel (interpreted off-TPU), False forces naive
     use_flash = cfg.flash is not False
+    n_chunks = _tp_chunks(B, tp_comm, sp_comm)
 
     def block(x, layer):
         wqkv, wo, w1, w2, g1, g2 = layer
+        b = x.shape[0]
         # the scopes name each op's HLO metadata (forward, backward and
         # remat alike) so a profile splits the step by layer part; they
         # end before the tp all-reduce, which the communicator names
@@ -141,9 +174,9 @@ def forward_hidden(params: dict, tokens, cfg: Config, tp_comm=None,
             if tp_comm is not None:
                 h = f_identity(tp_comm, h)
             qkv = jnp.einsum("bsd,dce->bsce", h, wqkv.astype(dtype))
-            q = qkv[:, :, 0].reshape(B, S, n_heads_local, hd)
-            k = qkv[:, :, 1].reshape(B, S, n_heads_local, hd)
-            v = qkv[:, :, 2].reshape(B, S, n_heads_local, hd)
+            q = qkv[:, :, 0].reshape(b, S, n_heads_local, hd)
+            k = qkv[:, :, 1].reshape(b, S, n_heads_local, hd)
+            v = qkv[:, :, 2].reshape(b, S, n_heads_local, hd)
             if sp_comm is not None:
                 if cfg.zigzag_sp:
                     from .ring_attention import ring_attention_zigzag
@@ -151,15 +184,15 @@ def forward_hidden(params: dict, tokens, cfg: Config, tp_comm=None,
                     o = ring_attention_zigzag(sp_comm, q, k, v)
                 else:
                     o = ring_attention(sp_comm, q, k, v, causal=True)
-                o = o.reshape(B, S, -1)
+                o = o.reshape(b, S, -1)
             elif use_flash:
                 from ..ops.flash_attention import flash_attention
 
                 o = flash_attention(
                     q, k, v, causal=True, force=cfg.flash is True
-                ).reshape(B, S, -1)
+                ).reshape(b, S, -1)
             else:
-                o = _attn(q, k, v).reshape(B, S, -1)
+                o = _attn(q, k, v).reshape(b, S, -1)
             o = jnp.einsum("bse,ed->bsd", o, wo.astype(dtype))
         if tp_comm is not None:
             o = g_allreduce(tp_comm, o)
@@ -173,22 +206,29 @@ def forward_hidden(params: dict, tokens, cfg: Config, tp_comm=None,
             d = jnp.einsum("bsf,fd->bsd", u, w2.astype(dtype))
         if tp_comm is not None:
             d = g_allreduce(tp_comm, d)
-        return x + d, None
+        return x + d
 
     layers = (
         params["wqkv"], params["wo"], params["w1"], params["w2"],
         params["ln1"], params["ln2"],
     )
-    step_fn = block
+    if n_chunks > 1:
+        def step_fn(chunks, layer):
+            return tuple(block(c, layer) for c in chunks), None
+
+        carry = tuple(jnp.split(x, n_chunks))
+    else:
+        def step_fn(x, layer):
+            return block(x, layer), None
+
+        carry = x
     if cfg.remat:
         step_fn = jax.checkpoint(
-            block,
+            step_fn,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         )
-    x, _ = lax.scan(
-        lambda carry, layer: step_fn(carry, layer), x,
-        layers,
-    )
+    carry, _ = lax.scan(step_fn, carry, layers)
+    x = jnp.concatenate(carry) if n_chunks > 1 else carry
     return _ln(x, params["lnf"], cfg.fused_ln)
 
 

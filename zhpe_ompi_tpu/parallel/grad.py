@@ -15,6 +15,17 @@ Without these, differentiating through a bare ``psum`` under
 ``check_vma=False`` applies the psum transpose (a second psum), scaling
 sharded-parameter gradients by the axis size — the bug class these wrappers
 exist to prevent.  (Verified numerically in tests/test_model.py.)
+
+Each tensor-parallel all-reduce sits between dependent matmuls, so on its
+own it can only stall the chip.  The layer loop
+(:func:`zhpe_ompi_tpu.models.transformer.forward_hidden`) therefore runs an
+even local batch as two independent half-batch chunks when tp has two
+ranks and sp is absent: one chunk's all-reduce then has the other chunk's
+work beside it, forward and backward.  Both wrappers call
+``comm.allreduce``; the communicator's decision layer
+(:func:`zhpe_ompi_tpu.coll.tuned.decide`) issues a two-rank allreduce on a
+TPU as one asynchronous swap, which is what lets it overlap.  The chunk
+count is the constant ``transformer.TP_CHUNKS`` (2), not an option.
 """
 
 from __future__ import annotations
