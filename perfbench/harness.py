@@ -17,6 +17,17 @@ harness reads
 
 so a later PR adds a cell, a configuration or a metric as new files and a
 new entry, and edits none of these.
+
+A reference module provides, for the configurations that name it:
+
+    dims(cfg)                     the sizes readers need (heads ``H``, head
+                                  width ``hd``, layers ``L``, ...)
+    init_params(cfg, key)         the weights of a run, made from the seed
+    train_readings(cfg, opt, ...) the numbers its driver compares
+    flops_per_step(cfg, traffic)  the model operations a step requires,
+                                  which ``mfu`` reads
+
+so a new configuration brings its own counts in its own file.
 """
 
 from __future__ import annotations

@@ -3,17 +3,20 @@ in %: the least time the chip needs for the work the causal forward and
 backward require (recomputation not counted), over the device time of the
 kernels' events (the forward that remat runs again included)."""
 
+import harness
 import trace_reduce
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 
-def required(cfg: dict, traffic: dict, dp: int, tp: int, elem_bytes: int):
-    """(flops, bytes) one chip's kernels need per step."""
+def required(m: dict, traffic: dict, dp: int, tp: int, elem_bytes: int):
+    """(flops, bytes) one chip's kernels need per step; ``m`` is the
+    reference module's ``dims`` of the configuration (heads ``H``, head
+    width ``hd``, layers ``L``)."""
     b = traffic["global_batch"] // dp
-    h = cfg["n_head"] // tp
-    hd = cfg["n_embd"] // cfg["n_head"]
-    S, L = traffic["seq"], cfg["n_layer"]
+    h = m["H"] // tp
+    hd = m["hd"]
+    S, L = traffic["seq"], m["L"]
     # per (row, head): forward QK^T and PV, backward dP, dV, dQ, dK, each
     # 2 S^2 hd at full square, halved by the causal mask
     flops = 6.0 * S * S * hd * b * h * L
@@ -31,8 +34,8 @@ def read(ctx):
     if seconds <= 0:
         return None
     mesh = cell.workload["mesh"]
-    flops, nbytes = required(cell.config, cell.traffic, mesh["dp"],
-                             mesh["tp"], 2)
+    m = harness.reference_module(cell).dims(cell.config)
+    flops, nbytes = required(m, cell.traffic, mesh["dp"], mesh["tp"], 2)
     pk = ctx["peak"]
     bound = max(flops / pk["bf16_flops"], nbytes / pk["hbm_bytes_per_s"])
     return 100.0 * bound * ctx["chips"] * red["steps"] / seconds

@@ -1,18 +1,11 @@
 """mfu: the whole step's share of the chips' bf16 peak, in %.
 
-Required model operations per step over the traced window: 6 x the matmul
-parameters x tokens (forward 2, backward 4; the unembedding counts, the
-embedding lookup does not) plus causal attention, 6 x L x B x S^2 x D
-(the full square's 12, halved for the mask).  Recomputation under remat and
-the chunked loss's second pass over the logits do not count."""
+The required model operations per step are the configuration's reference
+module's count (``flops_per_step``: the forward and backward the model
+needs, recomputation not counted), times the steps over the traced
+window."""
 
-
-def flops_per_step(cfg: dict, traffic: dict) -> float:
-    D, F, V, L = (cfg["n_embd"], cfg["n_inner"], cfg["vocab_size"],
-                  cfg["n_layer"])
-    B, S = traffic["global_batch"], traffic["seq"]
-    matmul_params = L * (4 * D * D + 2 * D * F) + V * D
-    return 6.0 * matmul_params * B * S + 6.0 * L * B * S * S * D
+import harness
 
 
 def read(ctx):
@@ -20,6 +13,7 @@ def read(ctx):
     if red["steps"] == 0 or red["window_s"] <= 0:
         return None
     cell = ctx["cell"]
-    rate = flops_per_step(cell.config, cell.traffic) * red["steps"] \
-        / red["window_s"]
+    flops = harness.reference_module(cell).flops_per_step(cell.config,
+                                                           cell.traffic)
+    rate = flops * red["steps"] / red["window_s"]
     return 100.0 * rate / (ctx["chips"] * ctx["peak"]["bf16_flops"])

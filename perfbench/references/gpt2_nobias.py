@@ -18,6 +18,28 @@ of the faults a training step can have, for the calibration readings.
 The weights of a run are made here too (``init_params``), from the seed,
 by the benchmark: the program is handed them, and the reference makes them
 again from the same seed rather than taking the program's arrays.
+
+So that the float32 pass fits one chip at long sequences, the reference
+is computed in blocks; each is a departure from the textbook layout, not
+from its arithmetic:
+
+- rows: ``block_rows(S)`` rows of the batch at a time, a budget of
+  ``BLOCK_TOKENS`` tokens (4 rows at S 1024, 1 at S 8192), their losses and
+  gradients summed;
+- attention: query blocks of ``Q_BLOCK`` positions, each against the keys
+  up to its own end under the causal mask, each under ``jax.checkpoint`` so
+  that the backward recomputes its scores rather than keeping them;
+- the unembedding and logsumexp: chunks of ``LOSS_CHUNK`` positions, each
+  under ``jax.checkpoint``, their sums added.
+
+Up to ``Q_BLOCK`` (``LOSS_CHUNK``) positions there is one block, and the
+computation is the unblocked one, op for op.  Blocking changes only the
+order of float32 sums; in the fp8 control, each block's operands take a
+scale of their own.
+
+``flops_per_step`` gives the model operations a training step of the
+configuration requires, which the ``mfu`` metric reads; ``dims`` the
+sizes other readers need.
 """
 
 from __future__ import annotations
@@ -30,8 +52,15 @@ import jax.numpy as jnp
 from jax import lax
 
 HIGHEST = lax.Precision.HIGHEST
-BLOCK_ROWS = 4   # rows of the batch run at once, so that the f32 pass fits
+BLOCK_TOKENS = 4096  # tokens of the batch run at once
+Q_BLOCK = 1024       # query positions of one block of attention
+LOSS_CHUNK = 1024    # positions of one chunk of the unembedding and its loss
 _NEG = -1e30
+
+
+def block_rows(seq: int) -> int:
+    """Rows of the batch run at once, so that the f32 pass fits."""
+    return max(1, BLOCK_TOKENS // seq)
 
 
 def dims(cfg: dict) -> dict:
@@ -39,6 +68,19 @@ def dims(cfg: dict) -> dict:
     return {"V": cfg["vocab_size"], "D": d, "H": cfg["n_head"],
             "hd": d // cfg["n_head"], "F": cfg["n_inner"],
             "L": cfg["n_layer"], "eps": cfg["layer_norm_epsilon"]}
+
+
+def flops_per_step(cfg: dict, traffic: dict) -> float:
+    """Required model operations of one training step: 6 x the matmul
+    parameters x tokens (forward 2, backward 4; the unembedding counts, the
+    embedding lookup does not) plus causal attention, 6 x L x B x S^2 x D
+    (the full square's 12, halved for the mask).  Recomputation under remat
+    and a chunked loss's second pass over the logits do not count."""
+    m = dims(cfg)
+    D, F, V, L = m["D"], m["F"], m["V"], m["L"]
+    B, S = traffic["global_batch"], traffic["seq"]
+    matmul_params = L * (4 * D * D + 2 * D * F) + V * D
+    return 6.0 * matmul_params * B * S + 6.0 * L * B * S * S * D
 
 
 def init_params(cfg: dict, key) -> dict:
@@ -118,6 +160,31 @@ def _gelu_tanh(u):
         math.sqrt(2.0 / math.pi) * (u + 0.044715 * u * u * u)))
 
 
+def _attend(q, k, v, q0, mode):
+    """Causal attention of the queries at positions ``q0`` onwards over
+    the keys ``k``, ``v`` from position 0."""
+    s = _mm("bqhd,bkhd->bhqk", q, k, mode) * (q.shape[-1] ** -0.5)
+    causal = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool), q0)
+    s = jnp.where(causal, s, _NEG)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return _mm("bhqk,bkhd->bqhd", p, v, mode)
+
+
+def attention(q, k, v, mode):
+    """Causal attention over query blocks of ``Q_BLOCK`` positions, each
+    seeing the keys up to its own end; one block up to ``Q_BLOCK``."""
+    S = q.shape[1]
+    if S <= Q_BLOCK:
+        return _attend(q, k, v, 0, mode)
+    out = []
+    for q0 in range(0, S, Q_BLOCK):
+        end = min(q0 + Q_BLOCK, S)
+        f = jax.checkpoint(functools.partial(_attend, q0=q0, mode=mode))
+        out.append(f(q[:, q0:end], k[:, :end], v[:, :end]))
+    return jnp.concatenate(out, axis=1)
+
+
 def _block(x, layer, m, mode, fault):
     wqkv, wo, w1, w2, g1, g2 = layer
     B, S, D = x.shape
@@ -125,12 +192,7 @@ def _block(x, layer, m, mode, fault):
     h = _ln(x, g1, m["eps"])
     qkv = _mm("bsd,dce->bsce", h, wqkv, mode)
     q, k, v = (qkv[:, :, i].reshape(B, S, H, hd) for i in range(3))
-    s = _mm("bqhd,bkhd->bhqk", q, k, mode) * (hd ** -0.5)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(causal, s, _NEG)
-    p = jnp.exp(s - s.max(-1, keepdims=True))
-    p = p / p.sum(-1, keepdims=True)
-    o = _mm("bhqk,bkhd->bqhd", p, v, mode).reshape(B, S, D)
+    o = attention(q, k, v, mode).reshape(B, S, D)
     F = w1.shape[-1]
     if fault == "tp_partial":
         # the tensor-parallel all-reduce left out: tp rank 0 of two keeps
@@ -142,6 +204,27 @@ def _block(x, layer, m, mode, fault):
     if fault == "tp_partial":
         u, w2 = u[..., :F // 2], w2[:F // 2]
     return x + _mm("bsf,fd->bsd", u, w2, mode)
+
+
+def _loss_chunk(x, targets, embed, mode):
+    """Sum over the chunk's tokens of -log p(target)."""
+    logits = _mm("bsd,vd->bsv", x, embed, mode)
+    mx = logits.max(-1, keepdims=True)
+    lse = jnp.log(jnp.exp(logits - mx).sum(-1)) + mx[..., 0]
+    tl = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
+    return jnp.sum(lse - tl)
+
+
+def loss_sum(x, targets, embed, mode):
+    """Sum of the token losses from the final hidden states, over chunks
+    of ``LOSS_CHUNK`` positions; one chunk up to ``LOSS_CHUNK``."""
+    S = x.shape[1]
+    if S <= LOSS_CHUNK:
+        return _loss_chunk(x, targets, embed, mode)
+    f = jax.checkpoint(functools.partial(_loss_chunk, mode=mode))
+    parts = [f(x[:, c0:c0 + LOSS_CHUNK], targets[:, c0:c0 + LOSS_CHUNK],
+               embed) for c0 in range(0, S, LOSS_CHUNK)]
+    return jnp.sum(jnp.stack(parts))
 
 
 def token_loss_sum(params, tokens, targets, m, mode="f32", fault=None,
@@ -158,11 +241,7 @@ def token_loss_sum(params, tokens, targets, m, mode="f32", fault=None,
         lambda c, layer: (_block(c, layer, m, mode, fault), None))
     x, _ = lax.scan(body, x, layers)
     x = _ln(x, params["lnf"], m["eps"])
-    logits = _mm("bsd,vd->bsv", x, params["embed"], mode)
-    mx = logits.max(-1, keepdims=True)
-    lse = jnp.log(jnp.exp(logits - mx).sum(-1)) + mx[..., 0]
-    tl = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
-    return jnp.sum(lse - tl)
+    return loss_sum(x, targets, params["embed"], mode)
 
 
 def loss_and_grads(params, tokens, targets, m, block_rows, mode="f32",
@@ -228,8 +307,8 @@ def train_readings(cfg, opt, batches, key, shardings=None, mode="f32",
                    out_shardings=shardings)
 
     def step(p, mu, nu, tok, tgt, t):
-        loss, g = loss_and_grads(p, tok, tgt, m, BLOCK_ROWS, mode, fault,
-                                 constrain)
+        loss, g = loss_and_grads(p, tok, tgt, m, block_rows(tok.shape[1]),
+                                 mode, fault, constrain)
         p, mu, nu = adamw_update(p, mu, nu, g, t, opt)
         return p, mu, nu, loss, leaf_norms(g)
 

@@ -15,6 +15,14 @@ def _cell(name):
     return harness.load_cell(run.ROOT, name)
 
 
+def _flops(c):
+    return harness.reference_module(c).flops_per_step(c.config, c.traffic)
+
+
+def _dims(c):
+    return harness.reference_module(c).dims(c.config)
+
+
 def test_model_flops_gpt2_medium():
     c = _cell("gpt2m-train-s1024")
     # 24 layers x (4 x 1024^2 + 2 x 1024 x 4096) + 50,304 x 1024
@@ -23,15 +31,32 @@ def test_model_flops_gpt2_medium():
     tokens = 16 * 1024
     # causal attention: half of 12 L B S^2 D
     attn = 12 * 24 * 16 * 1024 ** 2 * 1024 // 2
-    got = _metric("mfu").flops_per_step(c.config, c.traffic)
+    got = _flops(c)
     assert got == 6 * params * tokens + attn
     assert got == pytest.approx(37.22e12, rel=1e-3)
+
+
+def test_model_flops_gpt2_medium_s8192():
+    c = _cell("gpt2m-train-s8192")
+    # the same 353,501,184 matmul parameters and 16,384 tokens a step;
+    # causal attention 6 L B S^2 D at S 8192, B 2
+    got = _flops(c)
+    assert got == 6 * 353_501_184 * 16_384 + 6 * 24 * 2 * 8192 ** 2 * 1024
+    assert got == 54_541_789_691_904
+
+
+def test_every_configuration_reference_states_its_counts():
+    bench = harness.load_benchmark(run.ROOT)
+    for w in bench["workloads"]:
+        ref = harness.reference_module(_cell(w["name"]))
+        assert callable(ref.flops_per_step), w["name"]
+        assert callable(ref.dims), w["name"]
 
 
 def test_flash_counts_causal_half():
     c = _cell("gpt2m-train-s1024")
     flops, nbytes = _metric("flash_roofline").required(
-        c.config, c.traffic, 1, 1, 2)
+        _dims(c), c.traffic, 1, 1, 2)
     # per (row, head, layer): forward QK^T + PV = 4 S^2 hd, backward four
     # matmuls = 8 S^2 hd, all halved by the mask
     S, hd, b, h, L = 1024, 64, 16, 16, 24
@@ -43,8 +68,9 @@ def test_flash_counts_causal_half():
 
 def test_flash_counts_split_over_dp_tp():
     c = _cell("gpt2l-train-dp2tp2")
-    one = _metric("flash_roofline").required(c.config, c.traffic, 1, 1, 2)
-    split = _metric("flash_roofline").required(c.config, c.traffic, 2, 2, 2)
+    m = _dims(c)
+    one = _metric("flash_roofline").required(m, c.traffic, 1, 1, 2)
+    split = _metric("flash_roofline").required(m, c.traffic, 2, 2, 2)
     assert split[0] == pytest.approx(one[0] / 4)
     assert split[1] == pytest.approx(one[1] / 4)
 
