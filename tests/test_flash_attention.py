@@ -250,3 +250,44 @@ class TestDispatch:
         FakeDev.platform, FakeDev.device_kind = platform, kind
         monkeypatch.setattr(fa.jax, "devices", lambda: [FakeDev()])
         assert fa.on_tpu() is want
+
+
+class TestNarrowV:
+    """v narrower than q and k (latent attention: 192 against 128, here
+    scaled down): forward, dq and dk/dv kernels in interpret mode against
+    the reference, the scale taken from q's width."""
+
+    @staticmethod
+    def _qkv(dq=48, dv=32, S=128, seed=3):
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return (jax.random.normal(k1, (2, S, 2, dq), jnp.float32),
+                jax.random.normal(k2, (2, S, 2, dq), jnp.float32),
+                jax.random.normal(k3, (2, S, 2, dv), jnp.float32))
+
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_forward_at_v_width(self, causal):
+        q, k, v = self._qkv()
+        out = flash_attention(q, k, v, causal=causal, block_q=64,
+                              block_k=32, interpret=True)
+        assert out.shape == (2, 128, 2, 32)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(attn_reference(q, k, v, causal)),
+            rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_grads_at_v_width(self, causal):
+        q, k, v = self._qkv(seed=4)
+        w = jax.random.normal(jax.random.PRNGKey(9), (2, 128, 2, 32))
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+        got = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=32, block_k=64,
+            interpret=True)), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(lambda q, k, v: attn_reference(
+            q, k, v, causal)), argnums=(0, 1, 2))(q, k, v)
+        for g, r in zip(got, want):
+            assert g.shape == r.shape
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=1e-4, atol=1e-4)

@@ -162,15 +162,19 @@ class TestChunkedCE:
         assert abs(float(ref) - float(ck)) < 1e-3
 
     def test_dispatcher_gates(self):
-        """token_ce routes to the reference when chunking can't apply."""
+        """token_ce runs the reference for chunk None or one whole-vocab
+        chunk, and refuses a chunk that does not divide the vocab."""
         rng = np.random.default_rng(4)
         x = jnp.asarray(rng.normal(size=(1, 8, 16)), jnp.float32)
         emb = jnp.asarray(rng.normal(size=(48, 16)), jnp.float32)
         t = jnp.asarray(rng.integers(0, 48, (1, 8)))
         ref = float(fce.ce_reference(x, emb, t))
-        # 48 % 32 != 0 -> reference; chunk None -> reference; both equal
-        assert abs(float(fce.token_ce(x, emb, t, 32)) - ref) < 1e-6
+        # 48 % 32 != 0 and 64 > 48: no silent fallback
+        for bad in (32, 64):
+            with pytest.raises(ValueError, match="does not divide"):
+                fce.token_ce(x, emb, t, bad)
         assert abs(float(fce.token_ce(x, emb, t, None)) - ref) < 1e-6
+        assert abs(float(fce.token_ce(x, emb, t, 48)) - ref) < 1e-6
         # 16 divides 48: genuinely chunked, same value
         assert abs(float(fce.token_ce(x, emb, t, 16)) - ref) < 1e-5
 

@@ -282,11 +282,16 @@ def _param_specs(tp_ax):
     }
 
 
-def _sync_grads(grads, loss, dp_comm, tp_comm, sp_comm, dp, tp, sp):
+def _sync_grads(grads, loss, dp_comm, tp_comm, sp_comm, dp, tp, sp,
+                dp_sharded=frozenset()):
     """The gradient synchronization semantics (verified in tests against
     a single-device run) — ONE home for both train-step builders:
       - tp-sharded params (wqkv/wo/w1/w2): their grads are tp-local
         already; average over dp only.
+      - dp-sharded params (``dp_sharded``: experts spread over the dp
+        ranks): their grads are already whole, every rank's tokens having
+        reached them through the all-to-all's transpose; no dp all-reduce,
+        only the 1/dp of the dp mean.
       - replicated-over-tp params (embed/ln): with the f/g wrappers each
         tp rank holds the full tp-summed gradient; a tp-mean makes the
         update bitwise-identical across tp ranks.
@@ -296,7 +301,10 @@ def _sync_grads(grads, loss, dp_comm, tp_comm, sp_comm, dp, tp, sp):
     All syncs go through the framework's allreduce."""
     synced = {}
     for name, g in grads.items():
-        g = dp_comm.allreduce(g, zops.SUM) / dp
+        if name in dp_sharded:
+            g = g / dp
+        else:
+            g = dp_comm.allreduce(g, zops.SUM) / dp
         if sp_comm is not None:
             g = sp_comm.allreduce(g, zops.SUM) / sp
         if name in _TP_REPLICATED and tp_comm is not None:
@@ -350,19 +358,48 @@ def make_train_step(cfg: Config, mesh, dp_comm, tp_comm, sp_comm=None,
     return step, param_specs
 
 
+def _on_axis(spec, axis) -> bool:
+    """Whether a PartitionSpec shards any dimension over ``axis``."""
+    return any(a == axis or (isinstance(a, tuple) and axis in a)
+               for a in spec)
+
+
+def _init_like_params(optimizer, mesh, param_specs):
+    """``optimizer.init`` jitted with each parameter-shaped part of the
+    state placed as its parameter, the rest replicated.  (The moments are
+    zeros tied to no input, so a plain ``jax.jit(optimizer.init)`` would
+    leave them whole on one chip until the first update.)"""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    leaves = {k: NamedSharding(mesh, s) for k, s in param_specs.items()}
+    jitted = []  # built at the first call, when the state's form is known
+
+    def init(params):
+        if not jitted:
+            out = optax.tree_map_params(
+                optimizer, lambda _, s: s,
+                jax.eval_shape(optimizer.init, params), leaves,
+                transform_non_params=lambda _: NamedSharding(mesh, P()))
+            jitted.append(jax.jit(optimizer.init, out_shardings=out))
+        return jitted[0](params)
+
+    return init
+
+
 def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
                           sp_comm=None, optimizer=None, dcn_proc=None,
                           dcn_weight: float | None = None,
-                          dcn_sharded: bool = False):
+                          dcn_sharded: bool = False, loss=None,
+                          param_specs=None, on_aux=None):
     """Stateful-optimizer training step: the framework's SPMD grad
     computation composed with any optax GradientTransformation.
 
     The gradient pass is the same shard_map program ``make_train_step``
     builds (framework allreduces on the dp/tp/sp axes); the optimizer
-    update runs in a second jit whose optimizer-state shardings follow
-    from the gradient/parameter shardings by XLA propagation — Adam
-    moments land sharded exactly like their parameters with no
-    hand-written state specs.
+    update runs in a second jit.  ``init_opt_state`` places each
+    parameter-shaped part of the state (Adam's moments) as its parameter
+    (:func:`_init_like_params`), and the update keeps it there.
 
     ``dcn_proc``: a host-plane endpoint (TcpProc from ``host_init``)
     makes this a MULTI-SLICE step — the in-mesh-synced gradients are
@@ -372,6 +409,14 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
     rides the same bucketed sync (no extra per-step DCN round trip).
     ``dcn_weight``: this slice's fraction of the global batch when
     slices carry unequal batches (default: equal, 1/size).
+
+    ``loss``, ``param_specs``: another model's local loss
+    ``loss(params, tokens, targets)`` and its parameter specs (default:
+    this module's GPT-2 block, ``cfg``'s).  Leaves whose spec shards them
+    over the dp axis get no dp all-reduce (see :func:`_sync_grads`).
+    ``on_aux``: the loss returns ``(loss, aux)``; each step hands
+    ``on_aux`` the aux pytree, each leaf one value per dp rank, still on
+    the device.
 
     Returns ``(init_opt_state, step, param_specs)``: ``step(params,
     opt_state, tokens, targets) -> (params, opt_state, loss)``."""
@@ -385,29 +430,43 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
     dp = mesh.shape[dp_comm.axis]
     tp = mesh.shape[tp_comm.axis] if tp_comm is not None else 1
     sp = mesh.shape[sp_comm.axis] if sp_comm is not None else 1
-    param_specs = _param_specs(tp_comm.axis if tp_comm is not None else None)
+    if param_specs is None:
+        param_specs = _param_specs(
+            tp_comm.axis if tp_comm is not None else None)
+    dp_sharded = frozenset(k for k, s in param_specs.items()
+                           if _on_axis(s, dp_comm.axis))
 
     def spmd_grads(params, tokens, targets):
         def local_loss(p):
+            if loss is not None:
+                return loss(p, tokens, targets)
             return loss_fn(p, tokens, targets, cfg, tp_comm, sp_comm)
 
-        loss, grads = jax.value_and_grad(local_loss)(params)
-        return _sync_grads(
-            grads, loss, dp_comm, tp_comm, sp_comm, dp, tp, sp
-        )
+        if on_aux is None:
+            value, grads = jax.value_and_grad(local_loss)(params)
+            return _sync_grads(grads, value, dp_comm, tp_comm, sp_comm,
+                               dp, tp, sp, dp_sharded)
+        (value, aux), grads = jax.value_and_grad(
+            local_loss, has_aux=True)(params)
+        synced, value = _sync_grads(grads, value, dp_comm, tp_comm,
+                                    sp_comm, dp, tp, sp, dp_sharded)
+        return synced, value, jax.tree.map(lambda a: a.reshape(1), aux)
 
     sp_ax = sp_comm.axis if sp_comm is not None else None
     data_spec = P(dp_comm.axis, sp_ax)
+    out_specs = (param_specs, P())
+    if on_aux is not None:
+        out_specs += (P(dp_comm.axis),)
     grad_step = jax.jit(
         jax.shard_map(
             spmd_grads, mesh=mesh,
             in_specs=(param_specs, data_spec, data_spec),
-            out_specs=(param_specs, P()),
+            out_specs=out_specs,
             check_vma=False,
         )
     )
 
-    init_opt_state = jax.jit(optimizer.init)
+    init_opt_state = _init_like_params(optimizer, mesh, param_specs)
 
     def _apply(params, opt_state, grads):
         with jax.named_scope("zmpi.optimizer"):
@@ -434,7 +493,11 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
     # idle gap on the device can be told to belong to one or the other
     def step(params, opt_state, tokens, targets):
         with jax.profiler.TraceAnnotation("zmpi.train.grad"):
-            grads, loss = grad_step(params, tokens, targets)
+            if on_aux is None:
+                grads, loss = grad_step(params, tokens, targets)
+            else:
+                grads, loss, aux = grad_step(params, tokens, targets)
+                on_aux(aux)
         if dcn_proc is not None and dcn_proc.size > 1:
             from ..parallel import hybrid
 

@@ -123,15 +123,17 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc,
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                interpret: bool):
-    """Returns (out (B,S,h,hd), lse (B*h, S, 1) float32).  Requires S
-    divisible by both block sizes (the wrapper guarantees it)."""
+    """Returns (out (B,S,h,dv), lse (B*h, S, 1) float32), ``dv`` being
+    v's head width.  Requires S divisible by both block sizes (the
+    wrapper guarantees it)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, h, hd = q.shape
+    dv = v.shape[-1]
 
-    def fold(x):  # (B, S, h, hd) -> (B*h, S, hd)
-        return x.transpose(0, 2, 1, 3).reshape(B * h, S, hd)
+    def fold(x):  # (B, S, h, d) -> (B*h, S, d)
+        return x.transpose(0, 2, 1, 3).reshape(B * h, S, x.shape[-1])
 
     qf, kf, vf = fold(q), fold(k), fold(v)
     n_kb = S // block_k
@@ -150,20 +152,20 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, hd), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda bh, qi, kj: (bh, kj, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, qi, kj: (bh, kj, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, hd), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi, kj: (bh, qi, 0)),
             # (bh, S, 1): the trailing unit dim satisfies the TPU tiling
             # rule (block dims must divide (8, 128) or equal the array's)
             pl.BlockSpec((1, block_q, 1), lambda bh, qi, kj: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * h, S, hd), q.dtype),
+            jax.ShapeDtypeStruct((B * h, S, dv), q.dtype),
             jax.ShapeDtypeStruct((B * h, S, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -171,7 +173,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         interpret=interpret,
         name="flash_fwd",
     )(qf, kf, vf)
-    return out.reshape(B, h, S, hd).transpose(0, 2, 1, 3), lse
+    return out.reshape(B, h, S, dv).transpose(0, 2, 1, 3), lse
 
 
 # ---------------------------------------------------------------- backward
@@ -192,7 +194,7 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
     qb = q_ref[0].astype(jnp.float32) * scale          # (bq, hd), pre-scaled
     kb = k_ref[0].astype(jnp.float32)                  # (bk, hd)
     vb = v_ref[0].astype(jnp.float32)
-    dob = do_ref[0].astype(jnp.float32)                # (bq, hd)
+    dob = do_ref[0].astype(jnp.float32)                # (bq, dv)
     s = lax.dot_general(                                # scaled scores
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -295,9 +297,10 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, h, hd = q.shape
+    dv = v.shape[-1]
 
-    def fold(x):  # (B, S, h, hd) -> (B*h, S, hd)
-        return x.transpose(0, 2, 1, 3).reshape(B * h, S, hd)
+    def fold(x):  # (B, S, h, d) -> (B*h, S, d)
+        return x.transpose(0, 2, 1, 3).reshape(B * h, S, x.shape[-1])
 
     qf, kf, vf, of, gf = fold(q), fold(k), fold(v), fold(o), fold(g)
     # Δ = rowsum(dO ∘ O): one fused elementwise+reduce, cheap in plain XLA;
@@ -310,6 +313,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
 
     q_spec = pl.BlockSpec((1, block_q, hd), lambda bh, qi, kj: (bh, qi, 0))
     k_spec = pl.BlockSpec((1, block_k, hd), lambda bh, qi, kj: (bh, kj, 0))
+    v_spec = pl.BlockSpec((1, block_k, dv), lambda bh, qi, kj: (bh, kj, 0))
+    do_spec = pl.BlockSpec((1, block_q, dv), lambda bh, qi, kj: (bh, qi, 0))
     row_spec = pl.BlockSpec((1, block_q, 1), lambda bh, qi, kj: (bh, qi, 0))
 
     dq = pl.pallas_call(
@@ -318,7 +323,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
             n_kb=n_kb, causal=causal,
         ),
         grid=(B * h, n_qb, n_kb),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B * h, S, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
@@ -332,6 +337,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
     # k-major grid: swap the roles of axes 1/2 in the index maps
     q_spec2 = pl.BlockSpec((1, block_q, hd), lambda bh, kj, qi: (bh, qi, 0))
     k_spec2 = pl.BlockSpec((1, block_k, hd), lambda bh, kj, qi: (bh, kj, 0))
+    v_spec2 = pl.BlockSpec((1, block_k, dv), lambda bh, kj, qi: (bh, kj, 0))
+    do_spec2 = pl.BlockSpec((1, block_q, dv), lambda bh, kj, qi: (bh, qi, 0))
     row_spec2 = pl.BlockSpec((1, block_q, 1), lambda bh, kj, qi: (bh, qi, 0))
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -339,15 +346,15 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
             n_qb=n_qb, causal=causal,
         ),
         grid=(B * h, n_kb, n_qb),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=[k_spec2, k_spec2],
+        in_specs=[q_spec2, k_spec2, v_spec2, do_spec2, row_spec2, row_spec2],
+        out_specs=[k_spec2, v_spec2],
         out_shape=[
             jax.ShapeDtypeStruct((B * h, S, hd), k.dtype),
-            jax.ShapeDtypeStruct((B * h, S, hd), v.dtype),
+            jax.ShapeDtypeStruct((B * h, S, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, hd), jnp.float32),
-            pltpu.VMEM((block_k, hd), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
@@ -357,7 +364,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
     )(qf, kf, vf, gf, lse, delta)
 
     def unfold(x):
-        return x.reshape(B, h, S, hd).transpose(0, 2, 1, 3)
+        return x.reshape(B, h, S, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return unfold(dq), unfold(dk), unfold(dv)
 
@@ -390,7 +397,9 @@ def on_tpu() -> bool:
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     block_k: int = 1024, interpret: bool = False,
                     force: bool = False):
-    """Fused attention over (B, S, heads, head_dim) tensors.
+    """Fused attention over (B, S, heads, head_dim) tensors.  v's head
+    width may differ from q's and k's (latent attention's 128 against
+    192): the output and dv take v's width, the scale q's.
 
     Dispatch, on the input and the platform only:
 

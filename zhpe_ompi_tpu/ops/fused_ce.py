@@ -130,9 +130,13 @@ chunked_ce.defvjp(_ce_fwd, _ce_bwd)
 
 
 def token_ce(x, emb, targets, chunk: int | None = None):
-    """Dispatcher: chunked when ``chunk`` divides the vocab (and the
-    vocab is big enough to matter), reference otherwise."""
+    """Dispatcher: the reference with ``chunk`` None (or one chunk of the
+    whole vocab), chunked otherwise.  A ``chunk`` that does not divide the
+    vocab raises: asking for chunks and quietly getting the (B, S, V)
+    logits would move a step's memory peak with no sign."""
     v = emb.shape[0]
-    if chunk is None or v % chunk or v <= chunk:
+    if chunk is not None and v % chunk:
+        raise ValueError(f"ce chunk {chunk} does not divide the vocab {v}")
+    if chunk is None or v == chunk:
         return ce_reference(x, emb, targets)
     return chunked_ce(x, emb, targets, chunk)
