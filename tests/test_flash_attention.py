@@ -291,3 +291,143 @@ class TestNarrowV:
             assert g.shape == r.shape
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                        rtol=1e-4, atol=1e-4)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+class TestBf16:
+    """bf16 inputs, the precision every model cell runs: the kernels'
+    matmuls take bf16 operands and accumulate in f32, which is
+    ``attn_reference``'s own numerics on the same bf16 inputs.
+
+    Measured here (interpret mode, seeds 0-2 of these cases, output and
+    gradients of a weighted sum): relative L2 error against
+    ``attn_reference`` on the same bf16 inputs at most 5.4e-3, against
+    the f32 reference at most 5.3e-3, while ``attn_reference`` in bf16
+    itself reads 3.9e-3 to 6.6e-3 against f32 and never less than the
+    kernel; the largest single difference 1.6e-2, one bf16 step at
+    magnitudes 2-4.  Limits: 1e-2 relative L2, 3.2e-2 per element (two
+    such steps), and no further from f32 than ``attn_reference`` in
+    bf16.
+    """
+
+    CASES = {
+        "square": (128, 64, 64, 32, 32),
+        "uneven": (96, 64, 64, 32, 48),
+        "narrow_v": (128, 48, 32, 32, 64),
+    }
+
+    def _inputs(self, case, seed=0):
+        S, dq, dv, _, _ = self.CASES[case]
+        k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(seed), 4)
+        q = jax.random.normal(k1, (2, S, 2, dq), jnp.float32)
+        k = jax.random.normal(k2, (2, S, 2, dq), jnp.float32)
+        v = jax.random.normal(k3, (2, S, 2, dv), jnp.float32)
+        w = jax.random.normal(k4, (2, S, 2, dv), jnp.float32)
+        return q, k, v, w
+
+    def _attn(self, case, causal):
+        _, _, _, bq, bk = self.CASES[case]
+        return lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True)
+
+    @staticmethod
+    def _out_and_grads(fn, q, k, v, w):
+        def loss(q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+        return (fn(q, k, v),) + jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    def _check(self, got, ref_bf16, ref_f32):
+        for g, rb, rf in zip(got, ref_bf16, ref_f32):
+            assert g.dtype == jnp.bfloat16 and g.shape == rb.shape
+            assert _rel(g, rb) < 1e-2
+            assert _rel(g, rf) < 1e-2
+            assert _rel(g, rf) <= _rel(rb, rf)
+            np.testing.assert_allclose(np.asarray(g, np.float32),
+                                       np.asarray(rf, np.float32),
+                                       atol=3.2e-2, rtol=0)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("case", ["square", "uneven", "narrow_v"])
+    def test_forward_and_grads_match_references(self, case, causal):
+        q, k, v, w = self._inputs(case)
+        qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+        got = self._out_and_grads(self._attn(case, causal), qb, kb, vb, w)
+
+        def ref(q, k, v):
+            return attn_reference(q, k, v, causal)
+
+        ref_bf16 = self._out_and_grads(ref, qb, kb, vb, w)
+        ref_f32 = self._out_and_grads(ref, q, k, v, w)
+        self._check(got, ref_bf16, ref_f32)
+
+
+def _kernel_dots(dtype):
+    """{kernel name: [(lhs dtype, rhs dtype, preferred, result dtype)]}
+    of every ``dot_general`` inside the three flash ``pallas_call``s of
+    one forward and backward, narrow v, inputs in ``dtype``."""
+    q = jnp.zeros((1, 64, 2, 48), dtype)
+    v = jnp.zeros((1, 64, 2, 32), dtype)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, block_q=32, block_k=32, interpret=True
+        ).astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
+
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    def subjaxprs(eqn):
+        for p in eqn.params.values():
+            for x in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(x, ClosedJaxpr):
+                    yield x.jaxpr
+                elif isinstance(x, Jaxpr):
+                    yield x
+
+    def dots(j):
+        for e in j.eqns:
+            if e.primitive.name == "dot_general":
+                lhs, rhs = (x.aval.dtype for x in e.invars)
+                yield (lhs, rhs, e.params["preferred_element_type"],
+                       e.outvars[0].aval.dtype)
+            for sub in subjaxprs(e):
+                yield from dots(sub)
+
+    found = {}
+
+    def kernels(j):
+        for e in j.eqns:
+            if e.primitive.name == "pallas_call":
+                found[e.params["name"]] = list(dots(e.params["jaxpr"]))
+            else:
+                for sub in subjaxprs(e):
+                    kernels(sub)
+
+    kernels(jaxpr.jaxpr)
+    return found
+
+
+class TestMatmulOperands:
+    """The operand rule, read from the kernels' own jaxprs: every matmul
+    of the three kernels takes operands in the inputs' dtype and
+    accumulates in f32 (s and p·V forward; s, dO·Vᵀ and ds·K for dq;
+    s, dO·Vᵀ, pᵀ·dO and dsᵀ·Q for dk/dv)."""
+
+    COUNTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    def test_every_kernel_matmul_takes_the_input_dtype(self, dtype):
+        found = _kernel_dots(dtype)
+        assert {k: len(v) for k, v in found.items()} == self.COUNTS
+        want = (jnp.dtype(dtype), jnp.dtype(dtype), jnp.dtype(jnp.float32),
+                jnp.dtype(jnp.float32))
+        for name, dots in found.items():
+            for d in dots:
+                assert tuple(jnp.dtype(x) for x in d) == want, (name, d)

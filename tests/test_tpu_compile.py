@@ -75,20 +75,26 @@ def _grad_of(fn, n_args):
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-@pytest.mark.parametrize("shape", [(16, 512, 16, 64), (2, 4096, 16, 64)],
-                         ids=["seq512", "seq4096"])
+@pytest.mark.parametrize("shape", [(16, 512, 16, 64, 64),
+                                   (2, 4096, 16, 64, 64),
+                                   (2, 4096, 16, 192, 128)],
+                         ids=["seq512", "seq4096", "mla4096"])
 def test_flash_compiles(one_chip, shape, direction):
+    """(B, S, heads, q/k width, v width); the last is Moonlight's latent
+    attention as one chip of its four-chip cell holds it.  bf16 inputs,
+    so the kernels' matmuls take bf16 operands."""
     from zhpe_ompi_tpu.ops.flash_attention import _flash
 
-    S = shape[1]
+    B, S, h, hd, dv = shape
     block_q, block_k = min(512, S), min(1024, S)  # flash_attention's
 
     def fwd(q, k, v):
         return _flash(q, k, v, True, block_q, block_k, False)
 
     fn = fwd if direction == "fwd" else _grad_of(fwd, 3)
-    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-    _compile_for_chip(jax.jit(fn).lower(q, q, q))
+    q = jax.ShapeDtypeStruct((B, S, h, hd), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, S, h, dv), jnp.bfloat16, sharding=one_chip)
+    _compile_for_chip(jax.jit(fn).lower(q, q, v))
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])

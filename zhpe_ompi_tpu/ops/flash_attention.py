@@ -21,12 +21,26 @@ in-kernel from Q, K and the saved logsumexp:
     p  = exp(q·kᵀ·scale − lse)
     dp = dO·Vᵀ           dv += pᵀ·dO
     ds = p·(dp − Δ)      with Δ = rowsum(dO ∘ O)
-    dq += scale·ds·K     dk += scale·dsᵀ·Q
+    dq += ds·K           dk += dsᵀ·(scale·Q)
+    (dq scaled once when its accumulation ends)
 
 Accumulators live in VMEM scratch across the streamed grid axis and
 fully-masked causal tiles skip compute, so training-time memory stays
 O(block·S) like the forward — the naive O(S²) rebuild would OOM
 precisely the long-context runs this kernel exists for.
+
+Precision: every matmul takes its operands in the inputs' dtype and
+accumulates in f32 (``preferred_element_type``).  Q is scaled in its own
+dtype, and the f32 p and ds tiles are cast to that dtype just before
+they enter a matmul; the running max, the row sums, lse, Δ, the masking,
+the exponentials and every accumulator stay f32.  These are the numerics
+of ``attn_reference`` under autodiff (bf16 q·kᵀ, softmax weights cast to
+v's dtype, the f32 score cotangent cast back before the dq and dk
+products), so bf16 inputs get single-pass bf16 MXU work and f32 inputs
+keep f32 operands throughout.  On a TPU v5e Mosaic's default precision
+multiplies f32 operands in one bf16 pass as well, so up-casting the
+tiles would give the same numbers at head width 64 and cost 0.4–1% of
+the kernels' time, no more: the operands' dtype does not bound them.
 
 Off-TPU the auto path uses the reference jnp implementation;
 `interpret=True` runs the kernels on CPU for tests (the in-tree analog
@@ -80,12 +94,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc,
         l_sc[...] = jnp.zeros_like(l_sc)
 
     def _compute():
-        scale = hd ** -0.5
-        qb = q_ref[0].astype(jnp.float32) * scale      # (block_q, hd)
-        kb = k_ref[0].astype(jnp.float32)              # (block_k, hd)
-        vb = v_ref[0].astype(jnp.float32)
+        qs = q_ref[0] * hd ** -0.5                     # (block_q, hd)
+        vb = v_ref[0]
         s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
+            qs, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if causal:
@@ -102,7 +114,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc,
         corr = jnp.exp(m_prev - m_new)
         l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_sc[...] = acc_sc[...] * corr + lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_sc[...] = m_new
@@ -187,16 +199,18 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
         s  = (scale·Q)·Kᵀ  (masked)     p  = exp(s − lse)
         dp = dO·Vᵀ                      ds = p·(dp − Δ)
 
-    Returns (qb_scaled, kb, dob, p, ds), all f32.
+    Q is scaled in its own dtype, as ``attn_reference`` scales it, and
+    both matmuls take the tiles as loaded, accumulating in f32.
+
+    Returns (qs, kb, dob, p, ds): the scaled Q, K and dO tiles in the
+    inputs' dtype, p and ds in f32.
     """
     hd = q_ref.shape[-1]
-    scale = hd ** -0.5
-    qb = q_ref[0].astype(jnp.float32) * scale          # (bq, hd), pre-scaled
-    kb = k_ref[0].astype(jnp.float32)                  # (bk, hd)
-    vb = v_ref[0].astype(jnp.float32)
-    dob = do_ref[0].astype(jnp.float32)                # (bq, dv)
+    qs = q_ref[0] * hd ** -0.5                         # (bq, hd), pre-scaled
+    kb = k_ref[0]                                      # (bk, hd)
+    dob = do_ref[0]                                    # (bq, dv)
     s = lax.dot_general(                                # scaled scores
-        qb, kb, (((1,), (1,)), ((), ())),
+        qs, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     if causal:
@@ -209,11 +223,11 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
         s = jnp.where(col <= row, s, _NEG_INF)
     p = jnp.exp(s - lse_ref[0])                        # masked -> exp(-inf)=0
     dp = lax.dot_general(                               # dO · Vᵀ
-        dob, vb, (((1,), (1,)), ((), ())),
+        dob, v_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     ds = p * (dp - delta_ref[0])
-    return qb, kb, dob, p, ds
+    return qs, kb, dob, p, ds
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -230,13 +244,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
     def _compute():
-        scale = hd ** -0.5
         _, kb, _, _, ds = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
             block_q, block_k, causal,
         )
-        dq_sc[...] += lax.dot_general(                  # (scale·ds) · K
-            ds * scale, kb, (((1,), (0,)), ((), ())),
+        dq_sc[...] += lax.dot_general(                  # ds · K
+            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -247,7 +260,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(kj == n_kb - 1)
     def _finalize():
-        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
+        # dq = scale·(ds·K), scaled once here rather than on every tile
+        dq_ref[0] = (dq_sc[...] * hd ** -0.5).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -264,17 +278,17 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
     def _compute():
-        qb, _, dob, p, ds = _recompute_p_ds(
+        qs, _, dob, p, ds = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, kj,
             block_q, block_k, causal,
         )
         dv_sc[...] += lax.dot_general(                  # pᵀ · dO
-            p, dob, (((0,), (0,)), ((), ())),
+            p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        # dsᵀ · (scale·Q): qb is pre-scaled, so this IS scale·dsᵀ·Q
+        # dsᵀ · (scale·Q): qs is pre-scaled, so this IS scale·dsᵀ·Q
         dk_sc[...] += lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
+            ds.astype(qs.dtype), qs, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -400,6 +414,11 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     """Fused attention over (B, S, heads, head_dim) tensors.  v's head
     width may differ from q's and k's (latent attention's 128 against
     192): the output and dv take v's width, the scale q's.
+
+    The kernels' matmuls take operands in the inputs' dtype and
+    accumulate in f32, with the softmax statistics in f32: the numerics
+    of ``attn_reference`` under autodiff.  bf16 inputs give bf16
+    operands, f32 inputs f32 ones.
 
     Dispatch, on the input and the platform only:
 
