@@ -1,7 +1,7 @@
-"""The chip-facing scripts (bench.py, chip_smoke.py) and the killable
-probe idiom the device plane keeps (utils/deadline.run_probe).  Fast:
-on the CPU both scripts must refuse before measuring anything, and every
-probe case uses a stub source, never a real backend."""
+"""The chip-facing script (chip_smoke.py) and the killable probe idiom
+the device plane keeps (utils/deadline.run_probe).  Fast: on the CPU the
+script must refuse before measuring anything, and every probe case uses
+a stub source, never a real backend."""
 
 import json
 import os
@@ -12,7 +12,6 @@ import time
 import numpy as np
 import pytest
 
-import bench
 import chip_smoke
 from zhpe_ompi_tpu.utils import deadline
 
@@ -94,27 +93,6 @@ class TestProbeDeadline:
         assert kind == "error"
 
 
-class TestBenchRefuses:
-    def test_unknown_device_kind_raises(self):
-        class Dev:
-            device_kind = "TPU v99"
-
-        with pytest.raises(RuntimeError, match="no published bf16 peak"):
-            bench.chip_peak(Dev())
-
-    def test_v5e_peak_is_the_published_one(self):
-        class Dev:
-            device_kind = "TPU v5 lite"
-
-        assert bench.chip_peak(Dev()) == 197e12
-
-    def test_main_exits_nonzero_on_cpu(self, capsys):
-        with pytest.raises(SystemExit) as exc:
-            bench.main()
-        assert exc.value.code not in (0, None)
-        assert capsys.readouterr().out == ""
-
-
 class TestCompileCache:
     @pytest.fixture
     def cache_dir(self):
@@ -126,13 +104,13 @@ class TestCompileCache:
 
     def test_default_is_fixed_repo_path(self, cache_dir, monkeypatch):
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        bench.use_compile_cache()
+        chip_smoke.use_compile_cache()
         assert cache_dir() == os.path.join(_REPO, ".jax_cache")
 
     def test_env_dir_is_left_to_jax(self, cache_dir, monkeypatch):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
         before = cache_dir()
-        bench.use_compile_cache()
+        chip_smoke.use_compile_cache()
         assert cache_dir() == before
 
 

@@ -1,7 +1,6 @@
 """utils/deadline — the shared deadline-armed killable-probe idiom
-(extracted from bench.py's backend probe; the device liveness probe
-arms the same machinery).  Fast: every case uses a stub probe source,
-never jax, never a real backend."""
+(the device liveness probe arms the same machinery).  Fast: every case
+uses a stub probe source, never jax, never a real backend."""
 
 import time
 

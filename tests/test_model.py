@@ -5,11 +5,12 @@ semantics (the examples/ acceptance-test analog of SURVEY.md §4).
 """
 
 import numpy as np
+import optax
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import zhpe_ompi_tpu as zmpi
 from zhpe_ompi_tpu.models import transformer as tfm
@@ -38,6 +39,21 @@ def _single_device_step(cfg, params, tokens, targets, lr=1e-2):
     return jax.tree.map(lambda p, gg: p - lr * gg, params, g), l
 
 
+def _sgd_step(cfg, mesh, dp_comm, tp_comm, sp_comm=None, lr=1e-2):
+    """The train step with plain SGD, whose update ``p + (-lr * g)`` is
+    the reference's ``p - lr * g`` in f32."""
+    return tfm.make_train_step_optax(cfg, mesh, dp_comm, tp_comm, sp_comm,
+                                     optimizer=optax.sgd(lr))
+
+
+def _shard(params, mesh, specs):
+    # Copy through numpy: device_put can alias the source buffer as one
+    # replica shard, and the update's donation would then delete the
+    # reference params
+    return {k: jax.device_put(np.asarray(v), NamedSharding(mesh, specs[k]))
+            for k, v in params.items()}
+
+
 def test_dp_tp_step_matches_single_device(cfg):
     devs = np.asarray(jax.devices()[:8]).reshape(2, 4)
     mesh = Mesh(devs, axis_names=("dp", "tp"))
@@ -49,16 +65,12 @@ def test_dp_tp_step_matches_single_device(cfg):
 
     ref_params, ref_loss = _single_device_step(cfg, params, tokens, targets)
 
-    step, specs = tfm.make_train_step(cfg, mesh, dp_comm, tp_comm)
-    from jax.sharding import NamedSharding
-
-    sharded = {
-        k: jax.device_put(v, NamedSharding(mesh, specs[k]))
-        for k, v in params.items()
-    }
+    init_opt, step, specs = _sgd_step(cfg, mesh, dp_comm, tp_comm)
+    sharded = _shard(params, mesh, specs)
     dspec = NamedSharding(mesh, P("dp"))
-    new_params, loss = step(
-        sharded, jax.device_put(tokens, dspec), jax.device_put(targets, dspec)
+    new_params, _, loss = step(
+        sharded, init_opt(sharded),
+        jax.device_put(tokens, dspec), jax.device_put(targets, dspec)
     )
 
     np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
@@ -82,16 +94,12 @@ def test_dp_tp_sp_step_matches_single_device(cfg):
     tokens, targets = _data(cfg)
     ref_params, ref_loss = _single_device_step(cfg, params, tokens, targets)
 
-    step, specs = tfm.make_train_step(cfg, mesh, dp_comm, tp_comm, sp_comm)
-    from jax.sharding import NamedSharding
-
-    sharded = {
-        k: jax.device_put(v, NamedSharding(mesh, specs[k]))
-        for k, v in params.items()
-    }
+    init_opt, step, specs = _sgd_step(cfg, mesh, dp_comm, tp_comm, sp_comm)
+    sharded = _shard(params, mesh, specs)
     dspec = NamedSharding(mesh, P("dp", "sp"))
-    new_params, loss = step(
-        sharded, jax.device_put(tokens, dspec), jax.device_put(targets, dspec)
+    new_params, _, loss = step(
+        sharded, init_opt(sharded),
+        jax.device_put(tokens, dspec), jax.device_put(targets, dspec)
     )
     np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
     for k in params:
@@ -108,19 +116,15 @@ def test_loss_decreases(cfg):
     tp_comm = zmpi.Communicator(mesh, "tp", name="tp2")
     params = tfm.init_params(cfg, jax.random.PRNGKey(1))
     tokens, targets = _data(cfg)
-    step, specs = tfm.make_train_step(cfg, mesh, dp_comm, tp_comm, lr=0.05)
-    from jax.sharding import NamedSharding
-
-    sharded = {
-        k: jax.device_put(v, NamedSharding(mesh, specs[k]))
-        for k, v in params.items()
-    }
+    init_opt, step, specs = _sgd_step(cfg, mesh, dp_comm, tp_comm, lr=0.05)
+    sharded = _shard(params, mesh, specs)
+    opt_state = init_opt(sharded)
     dspec = NamedSharding(mesh, P("dp"))
     tokens = jax.device_put(tokens, dspec)
     targets = jax.device_put(targets, dspec)
     losses = []
     for _ in range(5):
-        sharded, loss = step(sharded, tokens, targets)
+        sharded, opt_state, loss = step(sharded, opt_state, tokens, targets)
         losses.append(float(loss))
     assert losses[-1] < losses[0]
 

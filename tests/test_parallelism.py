@@ -240,6 +240,8 @@ class TestZigzagTransformer:
         positional encoding, so the token->rank assignment must not
         change the math — only the causal structure, which the zigzag
         ring preserves by global position)."""
+        import optax
+
         import zhpe_ompi_tpu as zmpi
         from jax.sharding import Mesh, NamedSharding
         from zhpe_ompi_tpu.models import transformer as tfm
@@ -274,11 +276,14 @@ class TestZigzagTransformer:
                 jnp.asarray(tgt)[..., None], n))[i, :, :, 0]
              for i in range(n)], axis=1)
 
-        step, specs = tfm.make_train_step(cfg, mesh, dp_comm, None,
-                                          sp_comm)
-        sharded = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+        init_opt, step, specs = tfm.make_train_step_optax(
+            cfg, mesh, dp_comm, None, sp_comm, optimizer=optax.sgd(1e-2))
+        # through numpy: the update donates its inputs
+        sharded = {k: jax.device_put(np.asarray(v),
+                                     NamedSharding(mesh, specs[k]))
                    for k, v in params.items()}
         dspec = NamedSharding(mesh, P("dp", "sp"))
-        _, loss = step(sharded, jax.device_put(jnp.asarray(tz), dspec),
-                       jax.device_put(jnp.asarray(gz), dspec))
+        _, _, loss = step(sharded, init_opt(sharded),
+                          jax.device_put(jnp.asarray(tz), dspec),
+                          jax.device_put(jnp.asarray(gz), dspec))
         assert abs(float(loss) - ref) < 5e-4, (float(loss), ref)

@@ -111,20 +111,25 @@ def test_layernorm_compiles(one_chip, direction):
 
 
 def test_train_step_compiles(one_chip, monkeypatch):
-    """``make_train_step`` at the chip width, with the dispatchers
-    steered to their TPU branch (they ask ``jax.devices()``, which here
-    is the CPU)."""
-    import bench
+    """The AdamW train step the cells run, at a 4-layer cut of the chip
+    width on one chip, with the dispatchers steered to their TPU branch
+    (they ask ``jax.devices()``, which here is the CPU)."""
+    import optax
+
     import zhpe_ompi_tpu as zmpi
     from zhpe_ompi_tpu.models import transformer as tfm
     from zhpe_ompi_tpu.ops import flash_attention, fused_norm
 
     monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
     monkeypatch.setattr(fused_norm, "on_tpu", lambda: True)
-    cfg = bench.chip_config(512)
-    mesh = bench.dp_tp_mesh(list(one_chip.device_set))
+    cfg = tfm.Config(vocab=8192, d_model=1024, n_heads=16, d_ff=4096,
+                     n_layers=4, seq=512, dtype=jnp.bfloat16, remat=True,
+                     ce_chunk=1024)
+    mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1),
+                ("dp", "tp"))
     dp_comm = zmpi.Communicator(mesh, "dp", name="aot_dp")
-    step, specs = tfm.make_train_step(cfg, mesh, dp_comm, None)
+    init_opt, step, specs = tfm.make_train_step_optax(
+        cfg, mesh, dp_comm, None, optimizer=optax.adamw(1e-3))
     shapes = jax.eval_shape(
         lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
     params = {
@@ -132,9 +137,10 @@ def test_train_step_compiles(one_chip, monkeypatch):
                                 sharding=NamedSharding(mesh, specs[k]))
         for k, v in shapes.items()
     }
+    opt = jax.eval_shape(init_opt, params)
     tok = jax.ShapeDtypeStruct((16, cfg.seq), jnp.int32,
                                sharding=NamedSharding(mesh, P("dp")))
-    _compile_for_chip(step.lower(params, tok, tok))
+    _compile_for_chip(jax.jit(step).lower(params, opt, tok, tok))
 
 
 # ------------------------------------------------- names a profile reads

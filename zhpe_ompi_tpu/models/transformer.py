@@ -52,7 +52,7 @@ class Config:
     # policy keeps matmul outputs (checkpoint_dots) so only the cheap
     # elementwise/norm intermediates are recomputed.
     remat: bool = False
-    # round-4 MFU levers (bench.py's cap analysis named both):
+    # MFU levers (a profile of the step's cap named both):
     # fused layernorm Pallas kernel: None = auto (kernel on TPU,
     # reference jnp elsewhere), True/False forces
     fused_ln: bool | None = None
@@ -284,8 +284,9 @@ def _param_specs(tp_ax):
 
 def _sync_grads(grads, loss, dp_comm, tp_comm, sp_comm, dp, tp, sp,
                 dp_sharded=frozenset()):
-    """The gradient synchronization semantics (verified in tests against
-    a single-device run) — ONE home for both train-step builders:
+    """The gradient synchronization semantics of
+    :func:`make_train_step_optax` (verified in tests against a
+    single-device run):
       - tp-sharded params (wqkv/wo/w1/w2): their grads are tp-local
         already; average over dp only.
       - dp-sharded params (``dp_sharded``: experts spread over the dp
@@ -316,46 +317,6 @@ def _sync_grads(grads, loss, dp_comm, tp_comm, sp_comm, dp, tp, sp,
     if tp_comm is not None:
         loss = tp_comm.allreduce(loss, zops.SUM) / tp
     return synced, loss
-
-
-def make_train_step(cfg: Config, mesh, dp_comm, tp_comm, sp_comm=None,
-                    lr: float = 1e-2):
-    """Build the jitted SPMD training step over dp x tp (x sp): one
-    fused shard_map program — grads, sync (see :func:`_sync_grads`),
-    and the SGD update in a single jit (the structure bench.py's
-    HLO-parity comparison against plain JAX relies on)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    dp = mesh.shape[dp_comm.axis]
-    tp = mesh.shape[tp_comm.axis] if tp_comm is not None else 1
-    sp = mesh.shape[sp_comm.axis] if sp_comm is not None else 1
-    param_specs = _param_specs(tp_comm.axis if tp_comm is not None else None)
-
-    def spmd_step(params, tokens, targets):
-        def local_loss(p):
-            return loss_fn(p, tokens, targets, cfg, tp_comm, sp_comm)
-
-        loss, grads = jax.value_and_grad(local_loss)(params)
-        synced, loss = _sync_grads(
-            grads, loss, dp_comm, tp_comm, sp_comm, dp, tp, sp
-        )
-        new_params = jax.tree.map(
-            lambda p, g: (p - lr * g).astype(p.dtype), params, synced
-        )
-        return new_params, loss
-
-    sp_ax = sp_comm.axis if sp_comm is not None else None
-    data_spec = P(dp_comm.axis, sp_ax)
-    step = jax.jit(
-        jax.shard_map(
-            spmd_step,
-            mesh=mesh,
-            in_specs=(param_specs, data_spec, data_spec),
-            out_specs=(param_specs, P()),
-            check_vma=False,
-        )
-    )
-    return step, param_specs
 
 
 def _on_axis(spec, axis) -> bool:
@@ -389,17 +350,18 @@ def _init_like_params(optimizer, mesh, param_specs):
 
 def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
                           sp_comm=None, optimizer=None, dcn_proc=None,
-                          dcn_weight: float | None = None,
-                          dcn_sharded: bool = False, loss=None,
-                          param_specs=None, on_aux=None):
-    """Stateful-optimizer training step: the framework's SPMD grad
-    computation composed with any optax GradientTransformation.
+                          loss=None, param_specs=None, on_aux=None):
+    """The training step: the framework's SPMD grad computation
+    composed with any optax GradientTransformation (``optax.sgd(lr)``
+    for plain SGD).
 
-    The gradient pass is the same shard_map program ``make_train_step``
-    builds (framework allreduces on the dp/tp/sp axes); the optimizer
-    update runs in a second jit.  ``init_opt_state`` places each
-    parameter-shaped part of the state (Adam's moments) as its parameter
-    (:func:`_init_like_params`), and the update keeps it there.
+    The gradient pass is one shard_map program (the model's loss and
+    its gradient, synced by the framework's allreduces on the dp/tp/sp
+    axes, :func:`_sync_grads`); the optimizer update runs in a second
+    jit, which donates the old parameters and state.  ``init_opt_state``
+    places each parameter-shaped part of the state (Adam's moments) as
+    its parameter (:func:`_init_like_params`), and the update keeps it
+    there.
 
     ``dcn_proc``: a host-plane endpoint (TcpProc from ``host_init``)
     makes this a MULTI-SLICE step — the in-mesh-synced gradients are
@@ -407,8 +369,6 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
     (:func:`zhpe_ompi_tpu.parallel.hybrid.dcn_grad_sync`) between the
     two jits, the ICI-inside/DCN-outside composition.  The loss scalar
     rides the same bucketed sync (no extra per-step DCN round trip).
-    ``dcn_weight``: this slice's fraction of the global batch when
-    slices carry unequal batches (default: equal, 1/size).
 
     ``loss``, ``param_specs``: another model's local loss
     ``loss(params, tokens, targets)`` and its parameter specs (default:
@@ -501,43 +461,25 @@ def make_train_step_optax(cfg: Config, mesh, dp_comm, tp_comm,
         if dcn_proc is not None and dcn_proc.size > 1:
             from ..parallel import hybrid
 
-            if dcn_sharded:
-                # scaling path (round 4): each distinct device shard
-                # syncs with its same-index peer across slices — host
-                # memory and DCN traffic are O(unique shard bytes),
-                # shardings preserved with no reshard (identical meshes
-                # on every slice, fingerprint-enforced).  The loss
-                # scalar rides the same call's host-leaf bucket — no
-                # extra DCN round trip.
-                bundle = hybrid.dcn_grad_sync_sharded(
-                    dcn_proc,
-                    {"grads": grads,
-                     "loss": np.asarray(loss, np.float32)},
-                    weight=dcn_weight)
-                grads = bundle["grads"]
-                loss = jnp.asarray(bundle["loss"])
-            else:
-                # small-slice default: pack_tree gathers each gradient
-                # fully to numpy and one bucketed allreduce syncs it —
-                # fewer, larger messages, at the cost of full-tensor
-                # host replication per step
-                bundle = hybrid.dcn_grad_sync(
-                    dcn_proc,
-                    {"grads": grads,
-                     "loss": np.asarray(loss, np.float32)},
-                    weight=dcn_weight,
-                )
-                # Re-shard the synced host gradients explicitly before
-                # the jitted apply: feeding unsharded numpy would force
-                # XLA to re-infer layout from donated params and
-                # materialize a replicated copy on every device first.
-                grads = {
-                    k: jax.device_put(v, grad_shardings[k])
-                    for k, v in bundle["grads"].items()
-                }
-                # keep the return contract uniform across modes: loss
-                # is always a jax scalar
-                loss = jnp.asarray(bundle["loss"])
+            # pack_tree gathers each gradient fully to numpy and one
+            # bucketed allreduce syncs it: fewer, larger messages, at
+            # the cost of full-tensor host replication per step
+            bundle = hybrid.dcn_grad_sync(
+                dcn_proc,
+                {"grads": grads,
+                 "loss": np.asarray(loss, np.float32)},
+            )
+            # Re-shard the synced host gradients explicitly before the
+            # jitted apply: feeding unsharded numpy would force XLA to
+            # re-infer layout from donated params and materialize a
+            # replicated copy on every device first.
+            grads = {
+                k: jax.device_put(v, grad_shardings[k])
+                for k, v in bundle["grads"].items()
+            }
+            # keep the return contract uniform: loss is always a jax
+            # scalar
+            loss = jnp.asarray(bundle["loss"])
         with jax.profiler.TraceAnnotation("zmpi.train.apply"):
             new_params, opt_state = apply(params, opt_state, grads)
         return new_params, opt_state, loss

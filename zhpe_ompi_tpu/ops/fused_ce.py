@@ -1,9 +1,9 @@
 """Chunked cross-entropy (fused logit/lse pass) — the round-4 MFU lever.
 
-The round-3 cap analysis (bench.py docstring) measured the f32 (B, S, V)
+A round-3 analysis of the train step's cap measured the f32 (B, S, V)
 logit/lse pass at ~13% of step FLOPs running at HBM-bandwidth rate: the
-unembed matmul's f32 logits (16 x 512 x 8192 x 4B = 256 MB at the bench
-shape) are materialized to HBM, re-read for the logsumexp, and the
+unembed matmul's f32 logits (16 x 512 x 8192 x 4B = 256 MB at a B 16,
+S 512 step) are materialized to HBM, re-read for the logsumexp, and the
 autodiff backward materializes the same-sized softmax.  This module
 computes the identical loss WITHOUT ever materializing the full logits:
 

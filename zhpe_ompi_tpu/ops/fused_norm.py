@@ -1,6 +1,6 @@
 """Fused layernorm Pallas kernel (fwd + bwd) — the round-4 MFU lever.
 
-The round-3 cap analysis (bench.py docstring) measured the steady-state plateau at 38-39% MFU and named the HBM-bound
+A round-3 analysis of the train step's cap measured the steady-state plateau at 38-39% MFU and named the HBM-bound
 segments between matmuls: the f32 layernorms are pure bandwidth — XLA
 computes the row statistics and the normalize as separate passes with an
 f32 upcast materialized in between, so each LN costs ~3x the minimal
